@@ -110,6 +110,23 @@ def get_chip(name: str) -> ChipConfig:
         raise KeyError(f"unknown chip {name!r}; known: {sorted(CHIPS)}")
 
 
+# jax ``Device.device_kind`` strings of the chips above. A kind missing
+# here is an error, never a default: a clock or a peak must not be
+# borrowed from another chip.
+DEVICE_KINDS: Dict[str, ChipConfig] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P,
+}
+
+
+def chip_for_device_kind(kind: str) -> ChipConfig:
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"unknown device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}")
+
+
 @dataclasses.dataclass(frozen=True)
 class SystemConfig:
     chip: ChipConfig = TPU_V5E

@@ -23,52 +23,21 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention.decode_attention import (attend_block,
+                                                            partial_specs)
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                   l_ref, *, scale: float, block_size: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
-    length = len_ref[b]
-    start = j * block_size
-    live = start < length
-
-    q = q_ref[0, 0]                                           # [G, dh]
-    G = q.shape[0]
-
-    @pl.when(live)
-    def _compute():
-        k = k_ref[0, :, 0, :]                                 # [Bs, dh]
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [G, Bs]
-        cols = start + jax.lax.broadcasted_iota(jnp.int32, (G, block_size), 1)
-        s = jnp.where(cols < length, s, NEG_INF)
-        m = jnp.max(s, axis=-1)                               # [G]
-        p = jnp.exp(s - m[:, None])
-        p = jnp.where((m > 0.5 * NEG_INF)[:, None], p, 0.0)
-        l = jnp.sum(p, axis=-1)
-        o = jax.lax.dot_general(p.astype(v.dtype), v,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        o_ref[0, 0, 0] = o
-        m_ref[0, 0, 0] = m
-        l_ref[0, 0, 0] = l
-
-    @pl.when(jnp.logical_not(live))
-    def _dead():
-        o_ref[0, 0, 0] = jnp.zeros_like(o_ref[0, 0, 0])
-        m_ref[0, 0, 0] = jnp.full_like(m_ref[0, 0, 0], NEG_INF)
-        l_ref[0, 0, 0] = jnp.zeros_like(l_ref[0, 0, 0])
+    attend_block(q_ref[0, 0], k_ref[0], v_ref[0], j * block_size,
+                 len_ref[b], o_ref.at[0, 0, 0], m_ref.at[0, 0, 0],
+                 l_ref.at[0, 0, 0], scale=scale)
 
 
 def paged_decode_attention_kernel(q, pool_k, pool_v, tables, lengths, *,
@@ -77,46 +46,39 @@ def paged_decode_attention_kernel(q, pool_k, pool_v, tables, lengths, *,
     lengths: [B] int32 (valid positions within the gathered window).
 
     Returns partials (o [B,Hkv,nb,G,dh] f32, m, l [B,Hkv,nb,G]) — one
-    split per table entry, merged by the caller.
+    split per table entry, merged by the caller. The pool is viewed as
+    [N, Bs, Hkv*dh] (a free reshape): a K/V block is the (Bs, dh) lane
+    slice of one kv head in one pool block.
     """
     B, Hkv, G, dh = q.shape
-    block_size = pool_k.shape[1]
+    N, block_size = pool_k.shape[:2]
     nb = tables.shape[1]
-    grid = (B, Hkv, nb)
 
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=block_size)
-
+    out_specs, out_shape = partial_specs(
+        B, Hkv, nb, G, dh, lambda b, h, j, tbl, lens: (b, h, j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, Hkv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, G, dh),
                          lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, dh),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, dh),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((1, block_size, dh),
+                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h)),
+            pl.BlockSpec((1, block_size, dh),
+                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, dh),
-                         lambda b, h, j, tbl, lens: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G),
-                         lambda b, h, j, tbl, lens: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G),
-                         lambda b, h, j, tbl, lens: (b, h, j, 0)),
-        ],
+        out_specs=out_specs,
     )
-
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, nb, G, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, nb, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, nb, G), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k, pool_v)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
+      pool_k.reshape(N, block_size, Hkv * dh),
+      pool_v.reshape(N, block_size, Hkv * dh))
+    return o, m[..., 0], l[..., 0]

@@ -5,10 +5,13 @@ TPU-native design notes (vs the CUDA FlashAttention algorithm):
     [Bk, dh] with Bq=Bk=256 default -> ~(2*256*128*2B)*2 + accum 256*128*4B
     ≈ 0.6 MB per (q,kv) tile set, comfortably inside ~16 MB VMEM with
     double-buffered pipelines.
-  - MXU alignment: all matmul dims are multiples of 128 (dh is padded by the
-    wrapper if needed); softmax statistics live in 8x128-friendly [Bq] lanes.
-  - GQA is handled in the *index map*: query head h reads KV head
-    h // q_group, so KV tiles are never materialized per-q-head in HBM.
+  - Heads are folded into the lane axis: q/k/v arrive as [B, S, H*dh]
+    (a free reshape of [B, S, H, dh]), so every block tail is (rows, dh)
+    and meets Mosaic's (8, 128) tiling rule at dh=128. Softmax statistics
+    live in [Bq, 1] column scratch.
+  - GQA is handled in the *index map*: query head h reads the lane block
+    of KV head h // q_group, so KV tiles are never materialized per-q-head
+    in HBM.
   - The KV grid axis is sequential ("arbitrary"); the online-softmax partial
     state (acc, m, l) persists in VMEM scratch across KV steps — the TPU
     analogue of FlashAttention's per-CTA registers.
@@ -23,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -47,9 +48,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :]                                 # [Bq, dh]
-        k = k_ref[0, :, 0, :]                                 # [Bk, dh]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0]                                          # [Bq, dh]
+        k = k_ref[0]                                          # [Bk, dh]
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # [Bq, Bk]
@@ -59,29 +60,32 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             cols = j * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 1)
             s = jnp.where(cols <= rows, s, NEG_INF)
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                   # [Bq, 1]
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new > 0.5 * NEG_INF)[:, None], p, 0.0)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(m_new > 0.5 * NEG_INF, p, 0.0)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(j == kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(q, k, v, *, scale: float, causal: bool = True,
                            q_offset: int = 0, block_q: int = 256,
                            block_kv: int = 256, interpret: bool = False):
-    """q: [B, Sq, H, dh]; k, v: [B, Skv, Hkv, dh]; H % Hkv == 0."""
+    """q: [B, Sq, H, dh]; k, v: [B, Skv, Hkv, dh]; H % Hkv == 0.
+
+    Heads are folded into lanes before the call ([B, S, H*dh], a free
+    reshape) so the blocks are (rows, dh) slices of a 2-D tail."""
     B, Sq, H, dh = q.shape
     _, Skv, Hkv, _ = k.shape
     assert H % Hkv == 0
@@ -101,22 +105,23 @@ def flash_attention_kernel(q, k, v, *, scale: float, causal: bool = True,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dh), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, dh),
-                         lambda b, h, i, j, g=group: (b, j, h // g, 0)),
-            pl.BlockSpec((1, block_kv, 1, dh),
-                         lambda b, h, i, j, g=group: (b, j, h // g, 0)),
+            pl.BlockSpec((1, block_q, dh), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_kv, dh),
+                         lambda b, h, i, j, g=group: (b, j, h // g)),
+            pl.BlockSpec((1, block_kv, dh),
+                         lambda b, h, i, j, g=group: (b, j, h // g)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dh),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, dh),
+                               lambda b, h, i, j: (b, i, h)),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, dh), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(B, Sq, H * dh), k.reshape(B, Skv, Hkv * dh),
+      v.reshape(B, Skv, Hkv * dh)).reshape(B, Sq, H, dh)

@@ -3,9 +3,10 @@
 Runs a real (small) model through the policy-driven ``Cluster`` runtime —
 role-tagged engine pools + KV handoff + IFB + pluggable scheduler/router/
 rate-matcher — fed by a composable ``repro.workloads`` scenario, and
-prints SLA metrics. On a pod this is where the mesh + params_shardings
-would be installed (launch/dryrun.py proves those lower); on CPU we serve
-the smoke configs end-to-end.
+prints SLA metrics. It serves the smoke-sized config by default (the CPU
+tests' size); ``--full`` serves the registry config at its published
+widths, which is what one accelerator chip runs (``chip_smoke.py``). On an
+accelerator the real engines clock device time on the detected chip.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b \
       --prefill-engines 1 --decode-engines 2 --requests 16 --isl 64 --osl 16 \
@@ -24,9 +25,10 @@ import argparse
 import json
 import sys
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.hardware import CHIP_NAMES, get_chip
-from repro.serving.backends import BACKENDS, init_real_params, make_engine
+from repro.serving.backends import (BACKENDS, init_real_params, local_chip,
+                                    make_engine)
 from repro.serving.cluster import Cluster
 from repro.serving.elastic import ElasticConfig, ElasticRateMatcher
 from repro.serving.policies import (ChunkedPiggybackScheduler, ElasticPolicy,
@@ -79,10 +81,21 @@ def build_workload(args, vocab: int):
     return w, args.requests
 
 
-def main(argv=None):
+def model_config(arch: str, full: bool):
+    """The registry config at published widths, or its smoke twin."""
+    return get_config(arch) if full else get_smoke_config(arch)
+
+
+def main(argv=None, *, recorder=None):
+    """Parse ``argv``, serve, print the metrics JSON; returns the metrics.
+    ``recorder`` (a ``serving.tracing`` recorder) sees every request
+    event; ``--trace-out`` attaches a ``TraceRecorder`` instead."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2.5-3b",
-                    help="architecture family (smoke-sized for CPU)")
+                    help="architecture family")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published widths (default: the "
+                    "smoke-sized config)")
     ap.add_argument("--backend", choices=BACKENDS, default="real",
                     help="'real' runs jit'd forwards; 'sim' runs the "
                     "analytic-time SimEngine (no params, ~100x faster)")
@@ -111,10 +124,12 @@ def main(argv=None):
                     help="prefill:decode ratio for --rate-matcher static")
     ap.add_argument("--prefill-engines", type=int, default=1)
     ap.add_argument("--decode-engines", type=int, default=2)
-    ap.add_argument("--prefill-chip", choices=CHIP_NAMES, default="v5e",
-                    help="hardware class of the prefill pool (virtual step "
-                    "times scale by the chip's relative speed)")
-    ap.add_argument("--decode-chip", choices=CHIP_NAMES, default="v5e",
+    ap.add_argument("--prefill-chip", choices=CHIP_NAMES, default=None,
+                    help="hardware class of the prefill pool (on the CPU "
+                    "and the sim backend, virtual step times scale by the "
+                    "chip's relative speed; default: the detected chip "
+                    "with --backend real on an accelerator, else v5e)")
+    ap.add_argument("--decode-chip", choices=CHIP_NAMES, default=None,
                     help="hardware class of the decode pool")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=12)
@@ -132,7 +147,13 @@ def main(argv=None):
         ap.error("--calibrate fits the sim roofline scale; pass "
                  "--backend sim with it")
 
-    cfg = get_smoke_config(args.arch)
+    cfg = model_config(args.arch, args.full)
+    # real engines on an accelerator run on the chip that is there (the
+    # engine refuses any other); elsewhere the modelled default is v5e
+    detected = local_chip() if args.backend == "real" else None
+    default_chip = detected.name if detected else "v5e"
+    args.prefill_chip = args.prefill_chip or default_chip
+    args.decode_chip = args.decode_chip or default_chip
     params = None
     if args.backend == "real":          # sim serves without params
         params = init_real_params(cfg, args.seed)
@@ -182,7 +203,6 @@ def main(argv=None):
                            chip=get_chip(chip_name),
                            calibration=cal_by_chip.get(chip_name))
 
-    recorder = None
     if args.trace_out:
         from repro.serving.tracing import TraceRecorder
         recorder = TraceRecorder()
@@ -240,7 +260,7 @@ def main(argv=None):
         extra = {"transfers": cluster.stats.transfers,
                  "hardware": cluster.pool_hardware()}
 
-    if recorder is not None:
+    if args.trace_out:
         from repro.serving.obs import export_perfetto
         counts = export_perfetto(recorder, args.trace_out, metrics=metrics)
         print(f"# trace: {args.trace_out} ({counts['total']} events, "
@@ -261,4 +281,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

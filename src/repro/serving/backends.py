@@ -38,7 +38,23 @@ def make_engine(backend: str, engine_id: int, cfg, params=None, **kw):
 def init_real_params(cfg, seed: int = 0):
     """Params for the real backend, with jax imported here — not at the
     caller's module load — so sim-only invocations never pay for it. The
-    one param-init recipe every launcher and calibration path shares."""
+    one param-init recipe every launcher and calibration path shares.
+    Jitted, so each weight is drawn and cast in one program: no f32 copy
+    of a stacked weight is ever held on the device."""
+    from functools import partial
+
     import jax
     from repro.models import transformer as T
-    return T.init_params(cfg, jax.random.PRNGKey(seed))
+    return jax.jit(partial(T.init_params, cfg))(jax.random.PRNGKey(seed))
+
+
+def local_chip():
+    """The ``core.hardware`` chip this process's jax drives, or None on
+    the CPU. An accelerator whose ``device_kind`` has no entry in
+    ``hardware.DEVICE_KINDS`` raises."""
+    import jax
+    from repro.core.hardware import chip_for_device_kind
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return chip_for_device_kind(dev.device_kind)

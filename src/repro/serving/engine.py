@@ -32,13 +32,15 @@ payload is a ``PagedCache`` carrying only the request's own blocks
 bandwidth analysis of this hop lives in core/kv_transfer.py, which sizes
 the paged hop by block-rounded length, not capacity).
 
-Hardware is a per-engine property: an ``Engine`` built with a
+Hardware is a per-engine property. On the CPU, an ``Engine`` built with a
 ``core.hardware.ChipConfig`` scales its measured step wall-times by the
 chip's relative speed (``hardware.relative_speed``), so pools of different
 chips — compute-rich prefill, bandwidth-rich decode — coexist in one
 ``Cluster`` and the virtual clock reflects the modelled hardware, not the
-host. ``hardware`` names the chip class (straggler detection groups by it)
-and ``capacity_weight`` is the engine's serving capacity in
+host. On an accelerator the clock is the device's own time: the engine's
+chip is the detected one, its speed factor is 1, and asking for another
+chip is an error. ``hardware`` names the chip class (straggler detection
+groups by it) and ``capacity_weight`` is the engine's serving capacity in
 reference-chip-equivalents (elastic rate matching weighs pools by it
 instead of counting heads).
 """
@@ -54,6 +56,7 @@ import numpy as np
 from repro.core.hardware import ChipConfig, relative_speed
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.serving.backends import local_chip
 from repro.serving.blocks import BlockAllocator, BlockPoolExhausted
 from repro.serving.common import (EngineFailure, PrefixCache,  # noqa: F401
                                   StepLog)
@@ -110,6 +113,9 @@ class Engine:
     """One model instance. Thread-unsafe by design (driven by Orchestrator)."""
 
     backend = "real"
+    # attention path of the jitted steps; the Pallas kernels are not wired
+    # into the engine yet
+    attn_impl = "xla"
 
     def __init__(self, engine_id: int, cfg: ModelConfig, params,
                  *, slots: int = 8, capacity: int = 256,
@@ -128,8 +134,21 @@ class Engine:
         self.clock = 0.0                       # engine-local clock (s)
         self.step_times = StepLog(step_history)
         self._slow_factor = 1.0                # straggler injection (tests)
-        # hardware class: measured wall-times scale by 1/relative_speed so
-        # a v5p engine's virtual steps are ~2.8x shorter than a v5e's
+        # hardware class: on the CPU, measured wall-times scale by
+        # 1/relative_speed so a v5p engine's virtual steps are ~2.8x
+        # shorter than a v5e's; on an accelerator they are device time
+        device_chip = local_chip()
+        if device_chip is not None:
+            if chip is not None and chip.name != device_chip.name:
+                raise ValueError(
+                    f"engine {engine_id}: chip {chip.name} requested, but "
+                    f"this process drives a {device_chip.name}; device "
+                    "time is not rescaled to another chip")
+            if speed_factor not in (None, 1.0):
+                raise ValueError(f"engine {engine_id}: speed_factor "
+                                 f"{speed_factor} on a {device_chip.name}; "
+                                 "device time is not rescaled")
+            chip, speed_factor = device_chip, 1.0
         self.chip = chip
         self.hardware = chip.name if chip is not None else "uniform"
         if speed_factor is not None:
@@ -151,8 +170,10 @@ class Engine:
             assert chunk_size % block_size == 0, \
                 "paged chunked prefill needs chunk_size % block_size == 0"
 
+        impl = self.attn_impl
         self._prefill = jax.jit(
-            lambda p, i: T.prefill_full(p, cfg, i, capacity=capacity))
+            lambda p, i: T.prefill_full(p, cfg, i, capacity=capacity,
+                                        impl=impl))
         # jitted chunked-prefill wrappers, keyed (chunk, has_base_cache):
         # building a fresh jax.jit per call would discard jit's trace cache
         # and recompile on every request.
@@ -177,7 +198,7 @@ class Engine:
                 if chunk_size and cfg.block == "attn" else None)
             self._decode_paged = jax.jit(
                 lambda p, pool, tbl, pos, t: T.decode_step_paged(
-                    p, cfg, pool, tbl, pos, t),
+                    p, cfg, pool, tbl, pos, t, impl=impl),
                 donate_argnums=(1,))
             self._scatter = jax.jit(T.scatter_blocks, donate_argnums=(0,))
             self._gather = jax.jit(T.gather_blocks)
@@ -249,7 +270,8 @@ class Engine:
         to [L, nb, Bs, Hkvp, dh] block tensors (block-padded true length —
         never the slot capacity); logits are computed before any padding,
         so they match the dense engine's bit-for-bit."""
-        logits, cache = T.prefill_full(p, self.cfg, inputs)
+        logits, cache = T.prefill_full(p, self.cfg, inputs,
+                                       impl=self.attn_impl)
         S = inputs["tokens"].shape[1]
         Bs = self.block_size
         Sb = -(-S // Bs) * Bs
@@ -396,7 +418,8 @@ class Engine:
         if fn is None:
             fn = jax.jit(
                 lambda p, i, pool, tbl, start: T.prefill_chunked_paged(
-                    p, self.cfg, i, chunk, pool, tbl, start=start),
+                    p, self.cfg, i, chunk, pool, tbl, start=start,
+                    impl=self.attn_impl),
                 static_argnames=("start",), donate_argnums=(2,))
             self._paged_chunked_fns[chunk] = fn
         return fn

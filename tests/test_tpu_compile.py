@@ -1,0 +1,144 @@
+"""Ahead-of-time compiles for one chip of a described TPU v5e.
+
+The TPU compiler is installed even where no chip is attached, and it
+compiles for a described topology from shapes alone. So a BlockSpec the
+Mosaic lowering refuses, or a full-width serving step that does not fit
+the chip's HBM, fails here instead of on the chip. Nothing runs: these
+tests say nothing about results or times.
+
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, and every test worker imports this
+file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.hardware import TPU_V5E
+from repro.kernels.decode_attention.ops import (decode_attention,
+                                                decode_attention_paged)
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.rwkv6.ops import wkv
+from repro.models import transformer as T
+
+BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+QWEN = get_config("qwen2.5-3b")
+RWKV = get_config("rwkv6-1.6b")
+H, HKV, DH = QWEN.num_heads, QWEN.num_kv_heads, QWEN.dh
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"     # no compiler logs on disk
+
+    def restore_env():
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = log_dir
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        restore_env()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from the
+    # persistent cache: keep them out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    cc.reset_cache()
+    restore_env()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+
+
+# kernel -> (call, argument specs) at the widths the registry gives
+# qwen2.5-3b (attention, bf16) and rwkv6-1.6b (WKV heads, f32)
+KERNELS = {
+    "flash_attention": (
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        [((1, 512, H, DH), BF), ((1, 512, HKV, DH), BF),
+         ((1, 512, HKV, DH), BF)]),
+    "decode_attention": (
+        decode_attention,
+        [((8, H, DH), BF), ((8, 2048, HKV, DH), BF),
+         ((8, 2048, HKV, DH), BF), ((8,), I32)]),
+    # the serving engine's default block_size (8) and one bf16 tile (16)
+    "paged_decode_bs8": (
+        decode_attention_paged,
+        [((8, H, DH), BF), ((2049, 8, HKV, DH), BF),
+         ((2049, 8, HKV, DH), BF), ((8, 256), I32), ((8,), I32)]),
+    "paged_decode_bs16": (
+        decode_attention_paged,
+        [((8, H, DH), BF), ((1025, 16, HKV, DH), BF),
+         ((1025, 16, HKV, DH), BF), ((8, 128), I32), ((8,), I32)]),
+    "wkv": (
+        wkv,
+        [((1, 256, RWKV.num_heads, RWKV.dh), F32)] * 4
+        + [((RWKV.num_heads, RWKV.dh), F32),
+           ((1, RWKV.num_heads, RWKV.dh, RWKV.dh), F32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, specs = KERNELS[name]
+    compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def qwen_params(one_chip):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        T.abstract_params(QWEN))
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+def test_full_width_decode_step_paged_fits_v5e(one_chip, qwen_params):
+    """The engine's paged decode step: 8 slots of 2048 tokens over the
+    pool the engine sizes for them (block_size 8)."""
+    slots, capacity, bs = 8, 2048, 8
+    nb = capacity // bs
+    blocks = 1 + QWEN.num_layers * nb * (slots + 4)
+    kv = (blocks, bs, QWEN.padded_kv_heads, DH)
+    pool = dict(zip(("k", "v"), _shapes(one_chip, (kv, BF), (kv, BF))))
+    tables, pos, tokens = _shapes(
+        one_chip, ((QWEN.num_layers, slots, nb), I32), ((slots,), I32),
+        ((slots,), I32))
+    step = jax.jit(lambda p, pool, tbl, pos, t: T.decode_step_paged(
+        p, QWEN, pool, tbl, pos, t), donate_argnums=(1,))
+    compiled = step.lower(qwen_params, pool, tables, pos, tokens).compile()
+    assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
+
+
+def test_full_width_prefill_full_fits_v5e(one_chip, qwen_params):
+    """The prefill engine's step on one 2048-token prompt."""
+    (tokens,) = _shapes(one_chip, ((1, 2048), I32))
+    step = jax.jit(lambda p, t: T.prefill_full(p, QWEN, {"tokens": t}))
+    compiled = step.lower(qwen_params, tokens).compile()
+    assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
