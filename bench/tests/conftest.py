@@ -1,0 +1,8 @@
+"""Puts the benchmark (``bench/``) and the program (``src/``) on the path."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
