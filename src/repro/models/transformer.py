@@ -282,18 +282,20 @@ def attn_full(p, x, cfg: ModelConfig, *, pos_offset=0, impl="xla"):
     positions = pos_offset + jnp.arange(S)[None, :]
     q, k, v = _qkv(p, x, cfg, positions)
     hmap = _head_map(cfg)
-    kr = L.expand_kv(k, hmap)
-    vr = L.expand_kv(v, hmap)
-    if cfg.sliding_window:
-        o = L.sliding_window_attention_xla(q, kr, vr, cfg.sliding_window)
-    elif impl == "dense":
-        o = L.dense_attention(q, kr, vr, causal=True)
-    elif impl == "pallas":
-        assert cfg.padded_heads == cfg.num_heads, "pallas path: no padding"
-        from repro.kernels.flash_attention.ops import flash_attention
-        o = flash_attention(q, kr, vr, causal=True)
-    else:
-        o = L.causal_attention_xla(q, kr, vr)
+    with jax.named_scope("attention"):
+        kr = L.expand_kv(k, hmap)
+        vr = L.expand_kv(v, hmap)
+        if cfg.sliding_window:
+            o = L.sliding_window_attention_xla(q, kr, vr, cfg.sliding_window)
+        elif impl == "dense":
+            o = L.dense_attention(q, kr, vr, causal=True)
+        elif impl == "pallas":
+            assert cfg.padded_heads == cfg.num_heads, \
+                "pallas path: no padding"
+            from repro.kernels.flash_attention.ops import flash_attention
+            o = flash_attention(q, kr, vr, causal=True)
+        else:
+            o = L.causal_attention_xla(q, kr, vr)
     return _attn_out(p, o.astype(x.dtype), cfg), (k, v)
 
 
@@ -304,31 +306,33 @@ def _decode_attend(p, q, kd, vd, valid, cfg: ModelConfig):
     ops* in the same order — masked columns contribute exact zeros after
     the NEG_INF mask, so dense and paged token streams stay bit-identical
     (asserted corpus-wide by tests/test_paged.py)."""
-    B = q.shape[0]
-    scale = 1.0 / math.sqrt(cfg.dh)
-    if cfg.grouped_decode and cfg.can_group_decode:
-        # GQA without materializing the expanded KV: pack the q-head group
-        # into the einsum (the decode-attention kernel's MXU trick, in XLA)
-        Hkvp = cfg.padded_kv_heads
-        G = cfg.padded_heads // Hkvp
-        qg = q[:, 0].reshape(B, Hkvp, G, cfg.dh)
-        s = jnp.einsum("bhgd,bkhd->bhgk", qg, kd,
-                       preferred_element_type=f32) * scale  # [B,Hkv,G,C]
-        s = jnp.where(valid[:, None, None, :], s, L.NEG_INF)
-        pr = jax.nn.softmax(s.astype(f32), axis=-1)
-        o = jnp.einsum("bhgk,bkhd->bhgd", pr.astype(vd.dtype), vd,
-                       preferred_element_type=f32)
-        o = o.reshape(B, 1, cfg.padded_heads, cfg.dh)
-    else:
-        hmap = _head_map(cfg)
-        kr = L.expand_kv(kd, hmap)
-        vr = L.expand_kv(vd, hmap)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                       preferred_element_type=f32) * scale  # [B,H,1,C]
-        s = jnp.where(valid[:, None, None, :], s, L.NEG_INF)
-        pr = jax.nn.softmax(s.astype(f32), axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", pr.astype(vr.dtype), vr,
-                       preferred_element_type=f32)
+    with jax.named_scope("attention"):
+        B = q.shape[0]
+        scale = 1.0 / math.sqrt(cfg.dh)
+        if cfg.grouped_decode and cfg.can_group_decode:
+            # GQA without materializing the expanded KV: pack the q-head
+            # group into the einsum (the decode-attention kernel's MXU
+            # trick, in XLA)
+            Hkvp = cfg.padded_kv_heads
+            G = cfg.padded_heads // Hkvp
+            qg = q[:, 0].reshape(B, Hkvp, G, cfg.dh)
+            s = jnp.einsum("bhgd,bkhd->bhgk", qg, kd,
+                           preferred_element_type=f32) * scale  # [B,Hkv,G,C]
+            s = jnp.where(valid[:, None, None, :], s, L.NEG_INF)
+            pr = jax.nn.softmax(s.astype(f32), axis=-1)
+            o = jnp.einsum("bhgk,bkhd->bhgd", pr.astype(vd.dtype), vd,
+                           preferred_element_type=f32)
+            o = o.reshape(B, 1, cfg.padded_heads, cfg.dh)
+        else:
+            hmap = _head_map(cfg)
+            kr = L.expand_kv(kd, hmap)
+            vr = L.expand_kv(vd, hmap)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                           preferred_element_type=f32) * scale  # [B,H,1,C]
+            s = jnp.where(valid[:, None, None, :], s, L.NEG_INF)
+            pr = jax.nn.softmax(s.astype(f32), axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", pr.astype(vr.dtype), vr,
+                           preferred_element_type=f32)
     return o
 
 
@@ -374,13 +378,14 @@ def _chunk_attend(q, kr, vr, mask, cfg: ModelConfig):
     prefill paths: q [B,Sq,H,dh] against *expanded* kr/vr [B,C,H,dh] with
     causal mask [Sq,C]. Shared for the same reason as ``_decode_attend``:
     identical float ops keep dense and paged prefill logits bit-equal."""
-    scale = 1.0 / math.sqrt(cfg.dh)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                   preferred_element_type=f32) * scale
-    s = jnp.where(mask[None, None], s, L.NEG_INF)
-    pr = jax.nn.softmax(s.astype(f32), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", pr.astype(vr.dtype), vr,
-                   preferred_element_type=f32)
+    with jax.named_scope("attention"):
+        scale = 1.0 / math.sqrt(cfg.dh)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                       preferred_element_type=f32) * scale
+        s = jnp.where(mask[None, None], s, L.NEG_INF)
+        pr = jax.nn.softmax(s.astype(f32), axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", pr.astype(vr.dtype), vr,
+                       preferred_element_type=f32)
     return o
 
 
@@ -412,14 +417,15 @@ def attn_chunk(p, x, cfg: ModelConfig, k_cache, v_cache, kv_offset):
 
 
 def _ffn(p, x, cfg: ModelConfig):
-    xn = L.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        y, aux = moe_lib.moe_ffn(xn, p["moe"], cfg.moe,
-                                 combine_fp32=cfg.moe_combine_fp32,
-                                 expert_tp=cfg.moe_expert_tp)
-        return x + y, aux
-    y = L.swiglu(xn, p["wi_gate"], p["wi_up"], p["wo_ffn"])
-    return x + y, {}
+    with jax.named_scope("mlp"):
+        xn = L.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
+        if cfg.moe is not None:
+            y, aux = moe_lib.moe_ffn(xn, p["moe"], cfg.moe,
+                                     combine_fp32=cfg.moe_combine_fp32,
+                                     expert_tp=cfg.moe_expert_tp)
+            return x + y, aux
+        y = L.swiglu(xn, p["wi_gate"], p["wi_up"], p["wo_ffn"])
+        return x + y, {}
 
 
 def _zero_aux():
@@ -616,12 +622,13 @@ def prefill_full(params, cfg: ModelConfig, inputs, *, capacity: Optional[int] = 
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
     x, entries = jax.lax.scan(body_fn, emb, params["blocks"])
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
-                        preferred_element_type=f32)
-    logits = _mask_padded_vocab(logits, cfg)
-    logits = constrain(logits, "dp", "vocab")
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
+                            preferred_element_type=f32)
+        logits = _mask_padded_vocab(logits, cfg)
+        logits = constrain(logits, "dp", "vocab")
 
     cache = dict(entries)
     if cfg.block == "attn":
@@ -648,12 +655,13 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
         return x1, new_entry
 
     x, new_entries = jax.lax.scan(body, x, (params["blocks"], entries))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], head,
-                        preferred_element_type=f32)
-    logits = _mask_padded_vocab(logits, cfg)
-    logits = constrain(logits, "dp", "vocab")
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x[:, 0], head,
+                            preferred_element_type=f32)
+        logits = _mask_padded_vocab(logits, cfg)
+        logits = constrain(logits, "dp", "vocab")
     new_cache = dict(new_entries)
     new_cache["pos"] = pos + 1
     return logits, new_cache
@@ -705,11 +713,12 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
         off = jnp.array(lo, jnp.int32)
         x_out, kv = scan_layers(xc, kv, off)
         x_last = x_out
-    x = L.rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
-                        preferred_element_type=f32)
-    logits = _mask_padded_vocab(logits, cfg)
+    with jax.named_scope("head"):
+        x = L.rms_norm(x_last, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
+                            preferred_element_type=f32)
+        logits = _mask_padded_vocab(logits, cfg)
     cache = {"k": kv[0], "v": kv[1], "pos": jnp.full((B,), S, jnp.int32)}
     return logits, cache
 
@@ -790,8 +799,9 @@ def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
         o = decode_attention_paged(q[:, 0], pool_k, pool_v, tbl, pos + 1)
         o = o[:, None]                                        # [B,1,H,dh]
     else:
-        kd = pool_k[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
-        vd = pool_v[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
+        with jax.named_scope("kv_window"):
+            kd = pool_k[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
+            vd = pool_v[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
         valid = jnp.arange(W)[None, :] <= pos[:, None]
         o = _decode_attend(p, q, kd, vd, valid, cfg)
     return _attn_out(p, o.astype(x1.dtype), cfg), (pool_k, pool_v)
@@ -818,12 +828,13 @@ def decode_step_paged(params, cfg: ModelConfig, pool, tables, pos, tokens,
 
     (x, pk, pv), _ = jax.lax.scan(body, (x, pool["k"], pool["v"]),
                                   (params["blocks"], tables))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], head,
-                        preferred_element_type=f32)
-    logits = _mask_padded_vocab(logits, cfg)
-    logits = constrain(logits, "dp", "vocab")
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x[:, 0], head,
+                            preferred_element_type=f32)
+        logits = _mask_padded_vocab(logits, cfg)
+        logits = constrain(logits, "dp", "vocab")
     return logits, {"k": pk, "v": pv}, pos + 1
 
 
@@ -866,8 +877,9 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
                 k[0].reshape(cb, Bs, Hkvp, dh).astype(pk.dtype))
             pv = pv.at[wids].set(
                 v[0].reshape(cb, Bs, Hkvp, dh).astype(pv.dtype))
-            kd = pk[tbl].reshape(1, W, Hkvp, dh)
-            vd = pv[tbl].reshape(1, W, Hkvp, dh)
+            with jax.named_scope("kv_window"):
+                kd = pk[tbl].reshape(1, W, Hkvp, dh)
+                vd = pv[tbl].reshape(1, W, Hkvp, dh)
             ctx = lo + chunk_size
             if impl == "pallas":
                 assert cfg.padded_heads == cfg.num_heads, \
@@ -895,11 +907,12 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
     x_last = None
     for lo in range(start, S, chunk_size):
         x_last, pk, pv = chunk_layers(emb[:, lo:lo + chunk_size], pk, pv, lo)
-    x = L.rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
-                        preferred_element_type=f32)
-    logits = _mask_padded_vocab(logits, cfg)
+    with jax.named_scope("head"):
+        x = L.rms_norm(x_last, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
+                            preferred_element_type=f32)
+        logits = _mask_padded_vocab(logits, cfg)
     return logits, {"k": pk, "v": pv}
 
 

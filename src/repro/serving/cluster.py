@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.serving.common import EngineFailure
 from repro.serving.request import Request, sla_metrics
+from repro.serving.tracing import span
 
 if TYPE_CHECKING:       # Engine is annotation-only: the loop is backend-
     from repro.serving.engine import Engine     # agnostic (real or sim)
@@ -642,7 +643,8 @@ class Cluster:
         ``legacy_loop=True`` with byte-identical schedules and is gone;
         schedule identity is now certified by trace parity
         (``tests/test_fleet_scale.py``)."""
-        return self._step_event()
+        with span("serve.round"):
+            return self._step_event()
 
     def _fire_due_events(self) -> None:
         """Drain heap events scheduled at or before ``now``. ARRIVAL events
@@ -700,7 +702,8 @@ class Cluster:
                 continue
             if san is not None:
                 digest = san.state_digest(self)
-            req = self.scheduler.select(self, eng)
+            with span("serve.select"):
+                req = self.scheduler.select(self, eng)
             if san is not None:
                 san.check_hook_purity(self, "scheduler.select", digest)
             if req is None:
@@ -736,7 +739,8 @@ class Cluster:
         for req, tok, cache, src in self.pending_insert:
             if san is not None:
                 digest = san.state_digest(self)
-            target = self.router.route(self, req, src)
+            with span("serve.route"):
+                target = self.router.route(self, req, src)
             if san is not None:
                 san.check_hook_purity(self, "router.route", digest)
             if target is None:
@@ -799,33 +803,35 @@ class Cluster:
         except EngineFailure:
             self._fail_engine(eng)
             return True
-        dt = eng.step_times[-1]
-        self.now += dt
-        self.stats.decode_busy_s += dt
-        san = self.sanitizer
-        rec = self.recorder
-        if rec is not None:
-            rec.on_decode_step(eng, self.now - dt, self.now, len(nxt))
-        for slot, tok in nxt.items():
-            req = eng.slot_req[slot]
-            if san is not None:
-                san.on_token(req, eng, self.now)
-            req.output.append(tok)
-            req.token_times.append(self.now)
-            req._next_tok = tok
-            req.decode_active_s += dt   # unconditional: stall attribution
-            #                             is identical with tracing on/off
-            if req.done:
-                req.done_t = self.now
-                eng.evict(slot)
+        with span("serve.tokens"):
+            dt = eng.step_times[-1]
+            self.now += dt
+            self.stats.decode_busy_s += dt
+            san = self.sanitizer
+            rec = self.recorder
+            if rec is not None:
+                rec.on_decode_step(eng, self.now - dt, self.now, len(nxt))
+            for slot, tok in nxt.items():
+                req = eng.slot_req[slot]
                 if san is not None:
-                    san.on_complete(req, self.now)
-                if rec is not None:
-                    rec.on_complete(req, self.now)
-                if self._metrics is not None:
-                    self._metrics.on_complete(req, self.now)
-                if self._workload is not None:
-                    self._workload.on_complete(req, self.now)
-        if not eng.slot_req:        # drained: drop from the occupied set so
-            self._occupied.pop(id(eng), None)   # idle rounds skip it
+                    san.on_token(req, eng, self.now)
+                req.output.append(tok)
+                req.token_times.append(self.now)
+                req._next_tok = tok
+                # unconditional: stall attribution is identical with
+                # tracing on/off
+                req.decode_active_s += dt
+                if req.done:
+                    req.done_t = self.now
+                    eng.evict(slot)
+                    if san is not None:
+                        san.on_complete(req, self.now)
+                    if rec is not None:
+                        rec.on_complete(req, self.now)
+                    if self._metrics is not None:
+                        self._metrics.on_complete(req, self.now)
+                    if self._workload is not None:
+                        self._workload.on_complete(req, self.now)
+            if not eng.slot_req:    # drained: drop from the occupied set so
+                self._occupied.pop(id(eng), None)   # idle rounds skip it
         return True

@@ -63,6 +63,7 @@ from repro.serving.common import (EngineFailure, PrefixCache,  # noqa: F401
 #   (re-exported: the failure type, prefix cache, and step log are
 #    backend-agnostic — serving/common.py — so the jax-free SimEngine
 #    shares them)
+from repro.serving.tracing import bind_profiler, span, span_enabled
 
 
 class PagedCache:
@@ -170,10 +171,15 @@ class Engine:
             assert chunk_size % block_size == 0, \
                 "paged chunked prefill needs chunk_size % block_size == 0"
 
+        # host spans (serving/tracing.py) go to the profiler's clock
+        bind_profiler(jax.profiler.TraceAnnotation)
         impl = self.attn_impl
-        self._prefill = jax.jit(
-            lambda p, i: T.prefill_full(p, cfg, i, capacity=capacity,
-                                        impl=impl))
+
+        # each jitted step is a named function, so its HLO module and
+        # device ops read jit(<name>) in a profile
+        def prefill_full(p, i):
+            return T.prefill_full(p, cfg, i, capacity=capacity, impl=impl)
+        self._prefill = jax.jit(prefill_full)
         # jitted chunked-prefill wrappers, keyed (chunk, has_base_cache):
         # building a fresh jax.jit per call would discard jit's trace cache
         # and recompile on every request.
@@ -196,10 +202,12 @@ class Engine:
             self.prefix_cache = (
                 PrefixCache(chunk_size, on_evict=self._release_entry)
                 if chunk_size and cfg.block == "attn" else None)
-            self._decode_paged = jax.jit(
-                lambda p, pool, tbl, pos, t: T.decode_step_paged(
-                    p, cfg, pool, tbl, pos, t, impl=impl),
-                donate_argnums=(1,))
+
+            def decode_step_paged(p, pool, tbl, pos, t):
+                return T.decode_step_paged(p, cfg, pool, tbl, pos, t,
+                                           impl=impl)
+            self._decode_paged = jax.jit(decode_step_paged,
+                                         donate_argnums=(1,))
             self._scatter = jax.jit(T.scatter_blocks, donate_argnums=(0,))
             self._gather = jax.jit(T.gather_blocks)
             self._prefill_payload = jax.jit(self._prefill_payload_impl)
@@ -207,8 +215,10 @@ class Engine:
         else:
             self.prefix_cache = (PrefixCache(chunk_size) if chunk_size
                                  and cfg.block == "attn" else None)
-            self._decode = jax.jit(
-                lambda p, c, t: T.decode_step(p, cfg, c, t))
+
+            def decode_step(p, c, t):
+                return T.decode_step(p, cfg, c, t)
+            self._decode = jax.jit(decode_step)
             self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
             self.cache = T.init_cache(cfg, slots, capacity)
 
@@ -289,16 +299,20 @@ class Engine:
     def prefill(self, prompt: np.ndarray) -> Tuple[int, Any]:
         """Full prefill of one prompt; returns (first_token, payload)."""
         self._check()
-        t0 = time.perf_counter()
-        inputs = {"tokens": jnp.asarray(prompt)[None, :]}
-        if self.paged:
-            logits, blocks = self._prefill_payload(self.params, inputs)
-            cache = PagedCache(blocks, len(prompt))
-        else:
-            logits, cache = self._prefill(self.params, inputs)
-        tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
-        jax.block_until_ready(tok)
-        self._tick(t0)
+        with span("serve.prefill"):
+            t0 = time.perf_counter()
+            with span("serve.prefill.dispatch"):
+                inputs = {"tokens": jnp.asarray(prompt)[None, :]}
+                if self.paged:
+                    logits, blocks = self._prefill_payload(self.params,
+                                                           inputs)
+                    cache = PagedCache(blocks, len(prompt))
+                else:
+                    logits, cache = self._prefill(self.params, inputs)
+            with span("serve.prefill.sample"):
+                tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
+                jax.block_until_ready(tok)
+            self._tick(t0)
         return tok, cache
 
     def prefill_chunked(self, prompt: np.ndarray, chunk: int,
@@ -354,40 +368,49 @@ class Engine:
         S = len(prompt)
         pad = (-S) % chunk
         Sp = S + pad
-        toks = np.pad(prompt, (0, pad), constant_values=0)
-        start, entry = 0, None
-        if self.prefix_cache is not None:
-            entry, start = self.prefix_cache.lookup(prompt)
-        nb_total = Sp // Bs
-        nb_prefix = start // Bs
-        tbl = np.zeros((Lr, nb_total), np.int32)
-        self._reserve(Lr * (nb_total - nb_prefix))
-        if entry is not None:
-            tbl[:, :nb_prefix] = entry.ids[:, :nb_prefix]
-            self._alloc.ref(tbl[:, :nb_prefix].ravel().tolist())
-        fresh = self._alloc.alloc(Lr * (nb_total - nb_prefix))
-        tbl[:, nb_prefix:] = np.asarray(fresh, np.int32).reshape(
-            Lr, nb_total - nb_prefix)
-        t0 = time.perf_counter()
-        inputs = {"tokens": jnp.asarray(toks)[None, :]}
-        tbl_j = jnp.asarray(tbl)
-        logits, self.pool = self._paged_chunked_fn(chunk)(
-            self.params, inputs, self.pool, tbl_j, start=start)
-        blocks = self._gather(self.pool, tbl_j)
-        tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
-        self._tick(t0)
-        payload = PagedCache(jax.tree.map(np.asarray, blocks), Sp)
-        # prefix entry keeps the chunk-aligned true prefix; everything
-        # else (pad blocks) goes straight back to the pool. The request's
-        # refcounts transfer to the entry — the payload above is a copy.
-        n = (S // chunk) * chunk
-        nbk = n // Bs
-        if self.prefix_cache is not None and nbk > 0:
-            if nb_total > nbk:
-                self._alloc.free(tbl[:, nbk:].ravel().tolist())
-            self.prefix_cache.insert(prompt, PrefixBlocks(tbl[:, :nbk], n))
-        else:
-            self._alloc.free(tbl.ravel().tolist())
+        with span("serve.prefill"):
+            with span("serve.prefill.blocks"):
+                toks = np.pad(prompt, (0, pad), constant_values=0)
+                start, entry = 0, None
+                if self.prefix_cache is not None:
+                    entry, start = self.prefix_cache.lookup(prompt)
+                nb_total = Sp // Bs
+                nb_prefix = start // Bs
+                tbl = np.zeros((Lr, nb_total), np.int32)
+                self._reserve(Lr * (nb_total - nb_prefix))
+                if entry is not None:
+                    tbl[:, :nb_prefix] = entry.ids[:, :nb_prefix]
+                    self._alloc.ref(tbl[:, :nb_prefix].ravel().tolist())
+                fresh = self._alloc.alloc(Lr * (nb_total - nb_prefix))
+                tbl[:, nb_prefix:] = np.asarray(fresh, np.int32).reshape(
+                    Lr, nb_total - nb_prefix)
+            t0 = time.perf_counter()
+            with span("serve.prefill.dispatch"):
+                inputs = {"tokens": jnp.asarray(toks)[None, :]}
+                tbl_j = jnp.asarray(tbl)
+                logits, self.pool = self._paged_chunked_fn(chunk)(
+                    self.params, inputs, self.pool, tbl_j, start=start)
+            with span("serve.prefill.kv_out"):
+                blocks = self._gather(self.pool, tbl_j)
+            with span("serve.prefill.sample"):
+                tok = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
+            self._tick(t0)
+            with span("serve.prefill.kv_out"):
+                payload = PagedCache(jax.tree.map(np.asarray, blocks), Sp)
+            # prefix entry keeps the chunk-aligned true prefix; everything
+            # else (pad blocks) goes straight back to the pool. The
+            # request's refcounts transfer to the entry — the payload above
+            # is a copy.
+            with span("serve.prefill.blocks"):
+                n = (S // chunk) * chunk
+                nbk = n // Bs
+                if self.prefix_cache is not None and nbk > 0:
+                    if nb_total > nbk:
+                        self._alloc.free(tbl[:, nbk:].ravel().tolist())
+                    self.prefix_cache.insert(prompt,
+                                             PrefixBlocks(tbl[:, :nbk], n))
+                else:
+                    self._alloc.free(tbl.ravel().tolist())
         if on_chunk:
             for i in range((S - start + pad) // chunk):
                 on_chunk(i, max((S - start + pad) // chunk, 1))
@@ -400,14 +423,17 @@ class Engine:
         fn = self._chunked_fns.get((chunk, has_base))
         if fn is None:
             if has_base:
-                fn = jax.jit(
-                    lambda p, i, c, start: T.prefill_chunked(
+                def prefill_chunked_resume(p, i, c, start):
+                    return T.prefill_chunked(
                         p, self.cfg, i, chunk, capacity=self.capacity,
-                        cache=_grow_cache(c, self.capacity), start=start),
-                    static_argnames=("start",))
+                        cache=_grow_cache(c, self.capacity), start=start)
+                fn = jax.jit(prefill_chunked_resume,
+                             static_argnames=("start",))
             else:
-                fn = jax.jit(lambda p, i: T.prefill_chunked(
-                    p, self.cfg, i, chunk, capacity=self.capacity))
+                def prefill_chunked(p, i):
+                    return T.prefill_chunked(p, self.cfg, i, chunk,
+                                             capacity=self.capacity)
+                fn = jax.jit(prefill_chunked)
             self._chunked_fns[(chunk, has_base)] = fn
         return fn
 
@@ -416,11 +442,12 @@ class Engine:
         blocks are appended in place, the pool is never copied)."""
         fn = self._paged_chunked_fns.get(chunk)
         if fn is None:
-            fn = jax.jit(
-                lambda p, i, pool, tbl, start: T.prefill_chunked_paged(
-                    p, self.cfg, i, chunk, pool, tbl, start=start,
-                    impl=self.attn_impl),
-                static_argnames=("start",), donate_argnums=(2,))
+            def prefill_chunked_paged(p, i, pool, tbl, start):
+                return T.prefill_chunked_paged(p, self.cfg, i, chunk, pool,
+                                               tbl, start=start,
+                                               impl=self.attn_impl)
+            fn = jax.jit(prefill_chunked_paged, static_argnames=("start",),
+                         donate_argnums=(2,))
             self._paged_chunked_fns[chunk] = fn
         return fn
 
@@ -460,13 +487,32 @@ class Engine:
         engines scatter only the request's blocks (O(request), not
         O(capacity)); dense engines scatter a capacity-wide row."""
         self._check()
-        slot = self._free.pop()
-        if self.paged:
-            if not isinstance(cache_b1, PagedCache):
-                raise TypeError("paged engine got a dense handoff payload; "
-                                "mixed-layout fleets are unsupported")
-            nbk = cache_b1.blocks["k"].shape[1]
-            Lr = self.cfg.num_layers
+        with span("serve.insert"):
+            slot = self._free.pop()
+            if self.paged:
+                self._insert_paged(slot, cache_b1)
+            else:
+                if isinstance(cache_b1, PagedCache):
+                    raise TypeError("dense engine got a paged handoff "
+                                    "payload; mixed-layout fleets are "
+                                    "unsupported")
+                length = cache_b1["pos"][0]
+                src = {k: v for k, v in cache_b1.items() if k != "pos"}
+                self.cache = self._insert(self.cache, src, slot, length)
+        self.slot_req[slot] = req
+        req.slot = slot
+        req.engine_id = self.engine_id
+        return slot
+
+    def _insert_paged(self, slot: int, payload: PagedCache):
+        """Claim blocks for the payload in ``slot``'s table, then scatter
+        its KV into them (the scatter donates the pool: in place)."""
+        if not isinstance(payload, PagedCache):
+            raise TypeError("paged engine got a dense handoff payload; "
+                            "mixed-layout fleets are unsupported")
+        nbk = payload.blocks["k"].shape[1]
+        Lr = self.cfg.num_layers
+        with span("serve.insert.blocks"):
             try:
                 self._reserve(Lr * nbk)
                 ids = np.asarray(self._alloc.alloc(Lr * nbk),
@@ -474,23 +520,13 @@ class Engine:
             except BlockPoolExhausted:
                 self._free.append(slot)
                 raise
-            self.pool = self._scatter(
-                self.pool, jnp.asarray(ids),
-                {k: jnp.asarray(v) for k, v in cache_b1.blocks.items()})
             self._tables[:, slot, :] = 0
             self._tables[:, slot, :nbk] = ids
-            self._pos[slot] = cache_b1.length
-        else:
-            if isinstance(cache_b1, PagedCache):
-                raise TypeError("dense engine got a paged handoff payload; "
-                                "mixed-layout fleets are unsupported")
-            length = cache_b1["pos"][0]
-            src = {k: v for k, v in cache_b1.items() if k != "pos"}
-            self.cache = self._insert(self.cache, src, slot, length)
-        self.slot_req[slot] = req
-        req.slot = slot
-        req.engine_id = self.engine_id
-        return slot
+            self._pos[slot] = payload.length
+        with span("serve.insert.upload"):
+            self.pool = self._scatter(
+                self.pool, jnp.asarray(ids),
+                {k: jnp.asarray(v) for k, v in payload.blocks.items()})
 
     def evict(self, slot: int):
         """Free a slot. Paged: each of the request's blocks is one
@@ -526,13 +562,6 @@ class Engine:
     def _decode_step_paged(self, tokens_by_slot: Dict[int, int]):
         Bs = self.block_size
         Lr = self.cfg.num_layers
-        # grow: a slot whose next write crosses a block boundary gets a
-        # fresh block per layer *before* the jit'd step (O(1) host work)
-        for s in tokens_by_slot:
-            bi = int(self._pos[s]) // Bs
-            if bi < self._nb_max and self._tables[0, s, bi] == 0:
-                self._reserve(Lr)
-                self._tables[:, s, bi] = self._alloc.alloc(Lr)
         # pow2-bucketed window over the *active* context: the table slice
         # (and therefore the attention width) tracks what is live, so jit
         # retraces at most log2(nb_max) times while short contexts never
@@ -542,19 +571,47 @@ class Engine:
         while nb * Bs <= mx:
             nb *= 2
         nb = min(nb, self._nb_max)
-        t0 = time.perf_counter()
-        toks = np.zeros((self.slots,), np.int32)
-        for s, t in tokens_by_slot.items():
-            toks[s] = t
-        logits, self.pool, _ = self._decode_paged(
-            self.params, self.pool, jnp.asarray(self._tables[:, :, :nb]),
-            jnp.asarray(self._pos), jnp.asarray(toks))
-        nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size], axis=-1))
-        jax.block_until_ready(nxt)
-        for s in tokens_by_slot:
-            self._pos[s] += 1
-        self._tick(t0)
+        with span("serve.decode",
+                  **self._decode_counters(tokens_by_slot, nb)):
+            # grow: a slot whose next write crosses a block boundary gets a
+            # fresh block per layer *before* the jit'd step (O(1) host work)
+            with span("serve.decode.blocks"):
+                for s in tokens_by_slot:
+                    bi = int(self._pos[s]) // Bs
+                    if bi < self._nb_max and self._tables[0, s, bi] == 0:
+                        self._reserve(Lr)
+                        self._tables[:, s, bi] = self._alloc.alloc(Lr)
+            t0 = time.perf_counter()
+            with span("serve.decode.upload"):
+                toks = np.zeros((self.slots,), np.int32)
+                for s, t in tokens_by_slot.items():
+                    toks[s] = t
+                tables = jnp.asarray(self._tables[:, :, :nb])
+                pos = jnp.asarray(self._pos)
+                toks = jnp.asarray(toks)
+            with span("serve.decode.dispatch"):
+                logits, self.pool, _ = self._decode_paged(
+                    self.params, self.pool, tables, pos, toks)
+            with span("serve.decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size],
+                                            axis=-1))
+                jax.block_until_ready(nxt)
+            for s in tokens_by_slot:
+                self._pos[s] += 1
+            self._tick(t0)
         return {s: int(nxt[s]) for s in tokens_by_slot}
+
+    def _decode_counters(self, tokens_by_slot: Dict[int, int],
+                         nb: int) -> Dict[str, int]:
+        """The ``serve.decode`` span's counters, built only while a
+        profiler records: the step's window in blocks, slots, and the keys
+        the batch attends (each active slot's position plus the token it
+        writes)."""
+        if not span_enabled():
+            return {}
+        live = sum(int(self._pos[s]) + 1 for s in tokens_by_slot)
+        return {"window_blocks": nb, "slots": self.slots,
+                "live_tokens": live}
 
     @property
     def mean_step_s(self) -> float:

@@ -41,9 +41,18 @@ the hot path runs the exact ``is not None`` guard the hotpath budget
 (``analysis/hotpath.py``) already audits — zero allocations, zero calls.
 The fleet-scan loops inside ``TraceRecorder`` itself are *enabled-path
 only* and carry annotated ``why`` entries in ``analysis/baseline.json``.
+
+Separately, ``span`` marks where the *host* spends wall time, on the
+profiler's own clock: a real ``Engine`` binds
+``jax.profiler.TraceAnnotation`` here when it is built, and every
+``span("serve.…")`` then lands in the same ``.xplane.pb`` as the device
+operations while a profiler session records. Unbound (SimEngine fleets,
+CPU sweeps) or with no session running, ``span`` returns one shared
+``nullcontext``: it never imports jax and never formats a counter.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
@@ -51,7 +60,39 @@ from typing import Any, Dict, List, Optional, Tuple
 from repro.serving.metrics import WindowedRate
 
 __all__ = ["NullRecorder", "TraceRecorder", "FlightRecorder",
-           "LIFECYCLE_KINDS", "describe_engine"]
+           "LIFECYCLE_KINDS", "describe_engine", "bind_profiler", "span",
+           "span_enabled"]
+
+_NO_SPAN = contextlib.nullcontext()
+# the profiler's TraceMe class, bound when a real Engine is built; the
+# profiler is process-wide, so the binding is too
+_annotation = None
+
+
+def bind_profiler(annotation) -> None:
+    """Route ``span`` to ``annotation`` (``jax.profiler.TraceAnnotation``):
+    a context manager class taking ``(name, **counters)`` with a static
+    ``is_enabled()``. Idempotent."""
+    global _annotation
+    _annotation = annotation
+
+
+def span_enabled() -> bool:
+    """True while a profiler session records host spans. Call sites whose
+    counters cost work to compute build them only then."""
+    ann = _annotation
+    return ann is not None and ann.is_enabled()
+
+
+def span(name: str, **counters):
+    """A host span ``name`` (``serve.…``) with integer ``counters`` as its
+    stats, recorded while a profiler session runs; otherwise a shared
+    no-op context."""
+    ann = _annotation
+    if ann is None or not ann.is_enabled():
+        return _NO_SPAN
+    return ann(name, **counters)
+
 
 # request-lifecycle event kinds: ordered identically across backends when
 # schedules match (the structural-parity surface). Time-driven kinds
