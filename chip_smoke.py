@@ -177,7 +177,7 @@ def phase_kernels(seed: int):
     tables = np.zeros((B, nb), np.int32)
     for b, n in enumerate(nbs):
         tables[b, :n] = [next(ids) for _ in range(n)]
-    pk, pv = bf(randn(N, Bs, Hkv, dh)), bf(randn(N, Bs, Hkv, dh))
+    pk, pv = bf(randn(N, Bs, Hkv * dh)), bf(randn(N, Bs, Hkv * dh))
     out = decode_attention_paged(q, pk, pv, jnp.asarray(tables),
                                  jnp.asarray(lengths))
     ref = decode_attention_paged_ref(f32(q), f32(pk), f32(pv), tables,
@@ -220,7 +220,7 @@ def phase_serve(mode: str, seed: int, chip):
             self.requests, self.engines = [], []
 
         def on_episode_begin(self, cluster):
-            self.engines = [dict(e.describe(), attn_impl=e.attn_impl)
+            self.engines = [dict(e.describe(), decode_impl=e.decode_impl)
                             for e in cluster.engines()]
 
         def on_complete(self, req, t):
@@ -241,7 +241,7 @@ def phase_serve(mode: str, seed: int, chip):
         if e["hardware"] != chip.name or e["speed_factor"] != 1.0:
             fail(f"{mode}: engine {e['engine_id']} clocks {e['hardware']} "
                  f"x{e['speed_factor']}, not the detected {chip.name}")
-    impls = sorted({e["attn_impl"] for e in rec.engines})
+    impls = sorted({e["decode_impl"] for e in rec.engines})
     cfg = serve.model_config(ARCH, full=True)
     print(f"{mode}: {cfg.name} ({cfg.num_layers} layers, d_model "
           f"{cfg.d_model}, vocab {cfg.vocab_size}), {len(rec.engines)} "

@@ -33,8 +33,7 @@ def attend_block(q, k, v, start, length, o_ref, m_ref, l_ref, *,
     """One kv split's unnormalized partials: q [G, dh] against k/v
     [Bk, dh] whose first row is sequence position ``start``. Writes
     o [G, dh] and the row statistics m, l [G, 1]; a split wholly past
-    ``length`` writes (0, NEG_INF, 0), which the merge ignores. Shared
-    by the dense and the paged kernel."""
+    ``length`` writes (0, NEG_INF, 0), which the merge ignores."""
     G, block = q.shape[0], k.shape[0]
 
     @pl.when(start < length)

@@ -1,4 +1,5 @@
-"""Jit'd wrapper: split-KV partials + cross-split online-softmax reduce."""
+"""Jit'd wrappers: the dense split-KV kernel with its cross-split
+online-softmax merge, and the paged kernel."""
 from __future__ import annotations
 
 import math
@@ -47,21 +48,18 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
 def decode_attention_paged(q, pool_k, pool_v, tables, lengths, *,
                            interpret: bool = False):
     """Paged-layout decode attention. q: [B, H, dh]; pools:
-    [N, Bs, Hkv, dh]; tables: [B, nb] int32 block ids (the gathered
-    window, in sequence order); lengths: [B] valid positions within it.
+    [N, Bs, Hkv*dh] (kv heads folded into lanes); tables: [B, nb] int32
+    block ids in sequence order; lengths: [B] keys to attend (>= 1).
 
-    Returns [B, H, dh]. Each table entry is one kv split; the block table
-    is scalar-prefetched so the kernel's DMA pipeline follows the
-    indirection (see paged_decode.py).
+    Returns [B, H, dh]. The kernel reads each slot's live blocks in place
+    and normalizes its own output (see paged_decode.py): no merge here.
     """
     B, H, dh = q.shape
-    Hkv = pool_k.shape[2]
+    Hkv = pool_k.shape[2] // dh
     assert H % Hkv == 0
     G = H // Hkv
     scale = 1.0 / math.sqrt(dh)
-    qg = q.reshape(B, Hkv, G, dh)
-    o, m, l = paged_decode_attention_kernel(
-        qg, pool_k, pool_v, tables, lengths, scale=scale,
-        interpret=interpret)
-    o_all = _merge_splits(o, m, l)
-    return o_all.reshape(B, H, dh).astype(q.dtype)
+    o = paged_decode_attention_kernel(
+        q.reshape(B, Hkv, G, dh), pool_k, pool_v, tables, lengths,
+        scale=scale, interpret=interpret)
+    return o.reshape(B, H, dh)

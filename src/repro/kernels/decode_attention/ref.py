@@ -24,7 +24,7 @@ def decode_attention_ref(q, k_cache, v_cache, lengths, *, scale=None):
 
 def decode_attention_paged_ref(q, pool_k, pool_v, tables, lengths, *,
                                scale=None):
-    """Pure-numpy paged oracle. q: [B,H,dh]; pools: [N,Bs,Hkv,dh];
+    """Pure-numpy paged oracle. q: [B,H,dh]; pools: [N,Bs,Hkv*dh];
     tables: [B,nb]; lengths: [B]. -> [B,H,dh] (f32 math)."""
     q = np.asarray(q, np.float32)
     pool_k = np.asarray(pool_k, np.float32)
@@ -32,7 +32,8 @@ def decode_attention_paged_ref(q, pool_k, pool_v, tables, lengths, *,
     tables = np.asarray(tables)
     lengths = np.asarray(lengths)
     B, H, dh = q.shape
-    _, Bs, Hkv, _ = pool_k.shape
+    _, Bs, lanes = pool_k.shape
+    Hkv = lanes // dh
     nb = tables.shape[1]
     W = nb * Bs
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)

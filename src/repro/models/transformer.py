@@ -727,7 +727,7 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
 # Paged KV cache (block pool + per-layer block tables)
 #
 # Layout: one pool of KV blocks shared by every layer and request,
-#   pool = {"k", "v": [num_blocks, block_size, Hkvp, dh]}
+#   pool = {"k", "v": [num_blocks, block_size, Hkvp*dh]}
 # with per-request *per-layer* block tables [L, nb] (int32 block ids).
 # Host-side ownership/refcounts live in serving/blocks.py; everything here
 # is the pure compute: decode gathers K/V through the table, prefill
@@ -747,16 +747,17 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 
 def init_block_pool(cfg: ModelConfig, num_blocks: int, block_size: int):
-    """Zero-filled block pool {"k","v": [N, Bs, Hkvp, dh]}."""
-    Hkvp = cfg.padded_kv_heads
-    shape = (num_blocks, block_size, Hkvp, cfg.dh)
+    """Zero-filled block pool {"k","v": [N, Bs, Hkvp*dh]}: a token's kv
+    heads lie side by side in lanes, so one block is Bs lane-dense rows,
+    the layout the paged decode kernel reads in place."""
+    shape = (num_blocks, block_size, cfg.padded_kv_heads * cfg.dh)
     return {"k": jnp.zeros(shape, cfg.jdtype),
             "v": jnp.zeros(shape, cfg.jdtype)}
 
 
 def gather_blocks(pool, ids):
     """ids [L, nb] -> free-floating block tensors
-    {"k","v": [L, nb, Bs, Hkvp, dh]} — the paged KV-handoff payload (only
+    {"k","v": [L, nb, Bs, Hkvp*dh]} — the paged KV-handoff payload (only
     the request's own blocks travel, never the whole pool)."""
     return {"k": pool["k"][ids], "v": pool["v"][ids]}
 
@@ -775,15 +776,15 @@ def scatter_blocks(pool, ids, blocks):
 def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
                       impl="xla"):
     """One decode token against the paged layout. x1: [B,1,D] (normed);
-    pool_k/v: [N,Bs,Hkvp,dh]; tbl: [B,nb]; pos: [B]. Writes this token's
-    K/V into the slot's current block, then attends over the gathered
-    window W = nb*Bs. Inactive slots must point at the trash block with
-    pos=0 (their write lands there; nothing reads it).
+    pool_k/v: [N,Bs,Hkvp*dh]; tbl: [B,nb]; pos: [B]. Writes this token's
+    K/V into the slot's current block, then attends over the slot's first
+    pos+1 keys. Inactive slots must point at the trash block with pos=0
+    (their write lands there; nothing reads it).
 
     ``impl="pallas"`` attends through ``kernels/decode_attention``'s paged
-    split-KV kernel — no gather, the block table is scalar-prefetched;
-    ``"xla"`` gathers and runs the dense decode core (bit-equal logits
-    with the dense cache)."""
+    kernel, which reads each slot's live blocks where they lie in the
+    pool; ``"xla"`` gathers the pow2 window W = nb*Bs of every slot and
+    runs the dense decode core (bit-equal logits with the dense cache)."""
     B = x1.shape[0]
     Bs = pool_k.shape[1]
     W = tbl.shape[1] * Bs
@@ -791,12 +792,15 @@ def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
     bidx = jnp.arange(B)
     wblk = tbl[bidx, pos // Bs]                               # [B]
     off = pos % Bs
-    pool_k = pool_k.at[wblk, off].set(k[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[wblk, off].set(v[:, 0].astype(pool_v.dtype))
+    pool_k = pool_k.at[wblk, off].set(k[:, 0].reshape(B, -1)
+                                      .astype(pool_k.dtype))
+    pool_v = pool_v.at[wblk, off].set(v[:, 0].reshape(B, -1)
+                                      .astype(pool_v.dtype))
     if impl == "pallas":
         assert cfg.padded_heads == cfg.num_heads, "pallas path: no padding"
         from repro.kernels.decode_attention.ops import decode_attention_paged
-        o = decode_attention_paged(q[:, 0], pool_k, pool_v, tbl, pos + 1)
+        with jax.named_scope("attention"):
+            o = decode_attention_paged(q[:, 0], pool_k, pool_v, tbl, pos + 1)
         o = o[:, None]                                        # [B,1,H,dh]
     else:
         with jax.named_scope("kv_window"):
@@ -874,9 +878,9 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
             q, k, v = _qkv(layer_p, xn, cfg, positions)
             wids = tbl[lo // Bs:lo // Bs + cb]
             pk = pk.at[wids].set(
-                k[0].reshape(cb, Bs, Hkvp, dh).astype(pk.dtype))
+                k[0].reshape(cb, Bs, Hkvp * dh).astype(pk.dtype))
             pv = pv.at[wids].set(
-                v[0].reshape(cb, Bs, Hkvp, dh).astype(pv.dtype))
+                v[0].reshape(cb, Bs, Hkvp * dh).astype(pv.dtype))
             with jax.named_scope("kv_window"):
                 kd = pk[tbl].reshape(1, W, Hkvp, dh)
                 vd = pv[tbl].reshape(1, W, Hkvp, dh)

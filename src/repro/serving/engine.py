@@ -9,7 +9,7 @@ Decode uses continuous batching over fixed slots. Two KV layouts share the
 same public surface:
 
 - **paged** (default for the dense-attention family): KV lives in a block
-  pool ``[num_blocks, block_size, Hkvp, dh]`` shared by all layers and
+  pool ``[num_blocks, block_size, Hkvp*dh]`` shared by all layers and
   slots, addressed through per-layer block tables (``serving/blocks.py``
   owns the host-side refcounts). ``insert`` scatters only the request's
   blocks, ``evict`` is an O(1) refcount decrement per block, decode
@@ -75,7 +75,7 @@ class PagedCache:
     __slots__ = ("blocks", "length")
 
     def __init__(self, blocks: Dict[str, Any], length: int):
-        self.blocks = blocks            # {"k","v": [L, nb, Bs, Hkvp, dh]}
+        self.blocks = blocks            # {"k","v": [L, nb, Bs, Hkvp*dh]}
         self.length = int(length)
 
     @property
@@ -110,13 +110,20 @@ def _grow_cache(cache, capacity: int):
     return out
 
 
+def decode_impl(cfg: ModelConfig) -> str:
+    """Attention of the paged decode step: the Pallas kernel on a TPU for
+    the configs it takes (no padded heads, head size a multiple of the
+    128 lanes), else XLA, which is also the reference on the CPU."""
+    if (jax.default_backend() == "tpu"
+            and cfg.padded_heads == cfg.num_heads and cfg.dh % 128 == 0):
+        return "pallas"
+    return "xla"
+
+
 class Engine:
     """One model instance. Thread-unsafe by design (driven by Orchestrator)."""
 
     backend = "real"
-    # attention path of the jitted steps; the Pallas kernels are not wired
-    # into the engine yet
-    attn_impl = "xla"
 
     def __init__(self, engine_id: int, cfg: ModelConfig, params,
                  *, slots: int = 8, capacity: int = 256,
@@ -173,12 +180,13 @@ class Engine:
 
         # host spans (serving/tracing.py) go to the profiler's clock
         bind_profiler(jax.profiler.TraceAnnotation)
-        impl = self.attn_impl
+        # the dense layout's decode step always attends in XLA
+        self.decode_impl = impl = decode_impl(cfg) if self.paged else "xla"
 
         # each jitted step is a named function, so its HLO module and
-        # device ops read jit(<name>) in a profile
+        # device ops read jit(<name>) in a profile; prefill attends in XLA
         def prefill_full(p, i):
-            return T.prefill_full(p, cfg, i, capacity=capacity, impl=impl)
+            return T.prefill_full(p, cfg, i, capacity=capacity)
         self._prefill = jax.jit(prefill_full)
         # jitted chunked-prefill wrappers, keyed (chunk, has_base_cache):
         # building a fresh jax.jit per call would discard jit's trace cache
@@ -277,11 +285,10 @@ class Engine:
 
     def _prefill_payload_impl(self, p, inputs):
         """Full prefill -> (logits, handoff blocks). The cache is reshaped
-        to [L, nb, Bs, Hkvp, dh] block tensors (block-padded true length —
+        to [L, nb, Bs, Hkvp*dh] block tensors (block-padded true length —
         never the slot capacity); logits are computed before any padding,
         so they match the dense engine's bit-for-bit."""
-        logits, cache = T.prefill_full(p, self.cfg, inputs,
-                                       impl=self.attn_impl)
+        logits, cache = T.prefill_full(p, self.cfg, inputs)
         S = inputs["tokens"].shape[1]
         Bs = self.block_size
         Sb = -(-S // Bs) * Bs
@@ -292,8 +299,7 @@ class Engine:
                 pad = jnp.zeros((row.shape[0], Sb - S) + row.shape[2:],
                                 row.dtype)
                 row = jnp.concatenate([row, pad], axis=1)
-            blocks[kk] = row.reshape(row.shape[0], Sb // Bs, Bs,
-                                     *row.shape[2:])
+            blocks[kk] = row.reshape(row.shape[0], Sb // Bs, Bs, -1)
         return logits, blocks
 
     def prefill(self, prompt: np.ndarray) -> Tuple[int, Any]:
@@ -444,8 +450,7 @@ class Engine:
         if fn is None:
             def prefill_chunked_paged(p, i, pool, tbl, start):
                 return T.prefill_chunked_paged(p, self.cfg, i, chunk, pool,
-                                               tbl, start=start,
-                                               impl=self.attn_impl)
+                                               tbl, start=start)
             fn = jax.jit(prefill_chunked_paged, static_argnames=("start",),
                          donate_argnums=(2,))
             self._paged_chunked_fns[chunk] = fn
@@ -562,15 +567,20 @@ class Engine:
     def _decode_step_paged(self, tokens_by_slot: Dict[int, int]):
         Bs = self.block_size
         Lr = self.cfg.num_layers
-        # pow2-bucketed window over the *active* context: the table slice
-        # (and therefore the attention width) tracks what is live, so jit
-        # retraces at most log2(nb_max) times while short contexts never
-        # pay full-capacity attention
-        mx = max(int(self._pos[s]) for s in tokens_by_slot)
-        nb = 1
-        while nb * Bs <= mx:
-            nb *= 2
-        nb = min(nb, self._nb_max)
+        if self.decode_impl == "pallas":
+            # the kernel reads each slot's live blocks, whatever the
+            # table's width: one step for every context
+            nb = self._nb_max
+        else:
+            # pow2-bucketed window over the *active* context: the table
+            # slice (and therefore the attention width) tracks what is
+            # live, so jit retraces at most log2(nb_max) times while short
+            # contexts never pay full-capacity attention
+            mx = max(int(self._pos[s]) for s in tokens_by_slot)
+            nb = 1
+            while nb * Bs <= mx:
+                nb *= 2
+            nb = min(nb, self._nb_max)
         with span("serve.decode",
                   **self._decode_counters(tokens_by_slot, nb)):
             # grow: a slot whose next write crosses a block boundary gets a
@@ -604,9 +614,9 @@ class Engine:
     def _decode_counters(self, tokens_by_slot: Dict[int, int],
                          nb: int) -> Dict[str, int]:
         """The ``serve.decode`` span's counters, built only while a
-        profiler records: the step's window in blocks, slots, and the keys
-        the batch attends (each active slot's position plus the token it
-        writes)."""
+        profiler records: the width in blocks of the table the step is
+        given (its window), slots, and the keys the batch attends (each
+        active slot's position plus the token it writes)."""
         if not span_enabled():
             return {}
         live = sum(int(self._pos[s]) + 1 for s in tokens_by_slot)

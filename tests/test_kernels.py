@@ -76,13 +76,14 @@ def test_decode_attention_length_mask_exact():
     np.testing.assert_allclose(out1, out2, atol=1e-6)
 
 
-def _paged_case(rng, B, N, Bs, Hkv, dh, lengths):
-    """Pool + sequential per-sequence tables; pad columns -> trash block 0."""
-    pool_k = rng.standard_normal((N, Bs, Hkv, dh)).astype(np.float32)
-    pool_v = rng.standard_normal((N, Bs, Hkv, dh)).astype(np.float32)
-    nb = max(-(-int(l) // Bs) for l in lengths)
+def _paged_case(rng, B, N, Bs, Hkv, dh, lengths, nb=None):
+    """Pool [N, Bs, Hkv*dh] + per-sequence tables of blocks scattered
+    through it; columns past a sequence's blocks -> trash block 0."""
+    pool_k = rng.standard_normal((N, Bs, Hkv * dh)).astype(np.float32)
+    pool_v = rng.standard_normal((N, Bs, Hkv * dh)).astype(np.float32)
+    nb = nb or max(-(-int(l) // Bs) for l in lengths)
     tables = np.zeros((B, nb), np.int32)
-    ids = iter(range(1, N))
+    ids = iter(rng.permutation(np.arange(1, N)).tolist())
     for b, l in enumerate(lengths):
         for j in range(-(-int(l) // Bs)):
             tables[b, j] = next(ids)
@@ -110,13 +111,51 @@ def test_decode_attention_paged(B, H, Hkv, dh, Bs, N, lengths, dtype):
                                atol=tol(dtype), rtol=tol(dtype))
 
 
+# The serving shapes (block size 8, dh 128) against the XLA decode core
+# the engine runs everywhere else, over the gathered pow2 window. In f32
+# the two differ only in summation order and the online rescaling, so
+# 2e-5; in bf16 each p is rounded to bf16 at another scale (running max,
+# not normalized), up to 2^-8 of each weight, so 2e-2.
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "qwen3-14b"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_matches_xla_decode(arch, dtype):
+    from repro.configs import get_config
+    from repro.kernels.decode_attention.paged_decode import chunk_blocks
+    from repro.models.transformer import _decode_attend
+    cfg = get_config(arch)
+    H, Hkv, dh, Bs, nb = cfg.num_heads, cfg.num_kv_heads, cfg.dh, 8, 64
+    chunk = Bs * chunk_blocks(Bs, Hkv * dh, jnp.dtype(dtype).itemsize, nb)
+    # one key, one block, one past it, a chunk, one past a chunk, the
+    # whole table; the last slot is inactive: pos 0 on the trash block
+    lengths = (1, 8, 9, chunk, chunk + 1, nb * Bs)
+    B = len(lengths) + 1
+    rng = np.random.default_rng(5)
+    N = 1 + sum(-(-l // Bs) for l in lengths)
+    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh,
+                                         lengths + (0,), nb)
+    lens = np.asarray(lengths + (1,), np.int32)
+    q = jnp.asarray(rng.standard_normal((B, H, dh)), dtype)
+    pk, pv = jnp.asarray(pool_k, dtype), jnp.asarray(pool_v, dtype)
+    out = decode_attention_paged(q, pk, pv, jnp.asarray(tables),
+                                 jnp.asarray(lens), interpret=True)
+    W = nb * Bs
+    kd = pk[tables].reshape(B, W, Hkv, dh)
+    vd = pv[tables].reshape(B, W, Hkv, dh)
+    valid = jnp.arange(W)[None, :] < lens[:, None]
+    ref = _decode_attend(None, q[:, None], kd, vd, valid, cfg)[:, 0]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=tol(dtype), rtol=tol(dtype))
+
+
 def test_decode_attention_paged_garbage_block_immunity():
     """Trash-block contents and positions past `length` must not leak."""
     rng = np.random.default_rng(13)
     B, H, Hkv, dh, Bs, N = 2, 4, 2, 64, 16, 16
     lengths = np.array([20, 33], np.int32)
     q = rng.standard_normal((B, H, dh)).astype(np.float32)
-    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths)
+    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths,
+                                         nb=4)
     out1 = decode_attention_paged(
         jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
         jnp.asarray(tables), jnp.asarray(lengths), interpret=True)

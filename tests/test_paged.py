@@ -255,3 +255,40 @@ def test_engine_step_times_bounded(params):
     assert eng.mean_step_s > 0.0
     with pytest.raises(IndexError):         # trimmed prefix is gone
         eng.step_times[0]
+
+
+KERNEL_CFG = ModelConfig(name="kernel-tiny", family="dense", num_layers=1,
+                         d_model=256, num_heads=2, num_kv_heads=1, d_ff=64,
+                         vocab_size=VOCAB, remat=False, dtype="bfloat16")
+
+
+def _takes_kernel(fn, *args, **kwargs) -> bool:
+    return "pallas_call" in str(fn.trace(*args, **kwargs).jaxpr)
+
+
+@pytest.mark.parametrize("backend,pad_heads_to,impl", [
+    ("cpu", 0, "xla"),          # the CPU runs the XLA reference
+    ("tpu", 0, "pallas"),
+    ("tpu", 4, "xla"),          # padded heads: a config the kernel refuses
+])
+def test_engine_chooses_the_paged_decode_kernel(monkeypatch, backend,
+                                                pad_heads_to, impl):
+    """A paged engine attends in its decode step through the Pallas
+    kernel on a TPU, for a config without padded heads and with a
+    lane-aligned head size; its prefill steps always attend in XLA."""
+    import dataclasses
+    cfg = dataclasses.replace(KERNEL_CFG, pad_heads_to=pad_heads_to)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = Engine(0, cfg, params, slots=2, capacity=32, chunk_size=8)
+    assert eng.decode_impl == impl
+    z = np.zeros((2,), np.int32)
+    assert _takes_kernel(eng._decode_paged, params, eng.pool,
+                         eng._tables[:, :, :2], z, z) == (impl == "pallas")
+    tokens = {"tokens": np.zeros((1, 16), np.int32)}
+    tbl = np.zeros((cfg.num_layers, 2), np.int32)
+    assert not _takes_kernel(eng._prefill_payload, params, tokens)
+    assert not _takes_kernel(eng._paged_chunked_fn(8), params, tokens,
+                             eng.pool, tbl, start=0)
+    dense = Engine(1, cfg, params, slots=2, capacity=32, paged=False)
+    assert dense.decode_impl == "xla"

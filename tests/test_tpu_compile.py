@@ -10,6 +10,7 @@ The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import dataclasses
 import os
 
 import jax
@@ -82,15 +83,16 @@ KERNELS = {
         decode_attention,
         [((8, H, DH), BF), ((8, 2048, HKV, DH), BF),
          ((8, 2048, HKV, DH), BF), ((8,), I32)]),
-    # the serving engine's default block_size (8) and one bf16 tile (16)
+    # the serving engine's default block_size (8) and one bf16 tile (16),
+    # over the pool's [N, Bs, Hkv*dh] layout
     "paged_decode_bs8": (
         decode_attention_paged,
-        [((8, H, DH), BF), ((2049, 8, HKV, DH), BF),
-         ((2049, 8, HKV, DH), BF), ((8, 256), I32), ((8,), I32)]),
+        [((8, H, DH), BF), ((2049, 8, HKV * DH), BF),
+         ((2049, 8, HKV * DH), BF), ((8, 256), I32), ((8,), I32)]),
     "paged_decode_bs16": (
         decode_attention_paged,
-        [((8, H, DH), BF), ((1025, 16, HKV, DH), BF),
-         ((1025, 16, HKV, DH), BF), ((8, 128), I32), ((8,), I32)]),
+        [((8, H, DH), BF), ((1025, 16, HKV * DH), BF),
+         ((1025, 16, HKV * DH), BF), ((8, 128), I32), ((8,), I32)]),
     "wkv": (
         wkv,
         [((1, 256, RWKV.num_heads, RWKV.dh), F32)] * 4
@@ -125,7 +127,7 @@ def test_full_width_decode_step_paged_fits_v5e(one_chip, qwen_params):
     slots, capacity, bs = 8, 2048, 8
     nb = capacity // bs
     blocks = 1 + QWEN.num_layers * nb * (slots + 4)
-    kv = (blocks, bs, QWEN.padded_kv_heads, DH)
+    kv = (blocks, bs, QWEN.padded_kv_heads * DH)
     pool = dict(zip(("k", "v"), _shapes(one_chip, (kv, BF), (kv, BF))))
     tables, pos, tokens = _shapes(
         one_chip, ((QWEN.num_layers, slots, nb), I32), ((slots,), I32),
@@ -134,6 +136,41 @@ def test_full_width_decode_step_paged_fits_v5e(one_chip, qwen_params):
         p, QWEN, pool, tbl, pos, t), donate_argnums=(1,))
     compiled = step.lower(qwen_params, pool, tables, pos, tokens).compile()
     assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
+
+
+# (config, slots, table blocks of the engine, table blocks of the step):
+# cell 1's decode engine (16 slots, capacity 4352) at its widest window,
+# and one 10-layer stage of 40/8 heads (32 slots, capacity 2560) at 2048
+KERNEL_STEPS = {
+    "qwen2.5-3b": (QWEN, 16, 544, 544),
+    "qwen3-14b-l10": (dataclasses.replace(get_config("qwen3-14b"),
+                                          num_layers=10), 32, 320, 256),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_STEPS))
+def test_full_width_decode_step_on_kernel_fits_v5e(one_chip, name):
+    """The paged decode step on the Pallas kernel reads the pool where it
+    lies: it fits HBM, and its temporaries stay under a quarter of one
+    pool, where any copy, reshape or transpose of the pool would need a
+    whole one (the kernel's first version relaid both pools out)."""
+    cfg, slots, nb_max, nb = KERNEL_STEPS[name]
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        T.abstract_params(cfg))
+    blocks = 1 + cfg.num_layers * nb_max * (slots + 4)
+    kv = (blocks, 8, cfg.padded_kv_heads * cfg.dh)
+    pool = dict(zip(("k", "v"), _shapes(one_chip, (kv, BF), (kv, BF))))
+    tables, pos, tokens = _shapes(
+        one_chip, ((cfg.num_layers, slots, nb), I32), ((slots,), I32),
+        ((slots,), I32))
+    step = jax.jit(lambda p, pool, tbl, pos, t: T.decode_step_paged(
+        p, cfg, pool, tbl, pos, t, impl="pallas"), donate_argnums=(1,))
+    compiled = step.lower(params, pool, tables, pos, tokens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
+    pool_bytes = blocks * 8 * kv[2] * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
 
 
 def test_full_width_prefill_full_fits_v5e(one_chip, qwen_params):
