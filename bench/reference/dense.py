@@ -18,6 +18,9 @@ real position) and attention runs over blocks of ``QBLOCK`` queries.
 ``quant="fp8"`` is the control: the same pass with the inputs of every
 product rounded to float8 e4m3 (per-row scales for activations, per
 output column for weights), the precision step below bfloat16.
+
+``dims`` is the ``Dims`` of the ``dense_gqa`` family
+(``bench/families/dense_gqa.py``), whose ``served_gaps`` this is.
 """
 from __future__ import annotations
 
@@ -28,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from yardstick.model import Dims
 
 f32 = jnp.float32
 BUCKET = 1024
@@ -66,7 +68,7 @@ def _rope(x, pos, theta):
 
 
 @partial(jax.jit, static_argnames=("dims", "quant"))
-def _layer(x, blocks, i, dims: Dims, quant: Optional[str]):
+def _layer(x, blocks, i, dims, quant: Optional[str]):
     p = jax.tree.map(lambda a: a[i].astype(f32), blocks)
     S, D = x.shape
     H, K, dh = dims.heads, dims.kv_heads, dims.head_dim
@@ -113,7 +115,7 @@ def _embed_rows(embed, tokens):
 
 
 @partial(jax.jit, static_argnames=("dims", "quant"))
-def _head(x, lo, final_norm, head, targets, dims: Dims, quant):
+def _head(x, lo, final_norm, head, targets, dims, quant):
     """HEAD_ROWS rows of hidden states from row ``lo`` -> (best logit,
     logit of target, argmax) per row."""
     h = jax.lax.dynamic_slice_in_dim(x, lo, HEAD_ROWS)
@@ -124,7 +126,7 @@ def _head(x, lo, final_norm, head, targets, dims: Dims, quant):
     return best, at, jnp.argmax(logits, -1).astype(jnp.int32)
 
 
-def hidden(params, dims: Dims, tokens: np.ndarray, quant=None):
+def hidden(params, dims, tokens: np.ndarray, quant=None):
     """Hidden states before the final norm, float32, one row per token and
     at least HEAD_ROWS rows of padding after them."""
     n = len(tokens)
@@ -138,7 +140,7 @@ def hidden(params, dims: Dims, tokens: np.ndarray, quant=None):
     return x
 
 
-def head_stats(params, dims: Dims, x, row0: int, targets, quant=None):
+def head_stats(params, dims, x, row0: int, targets, quant=None):
     """For the rows of x from ``row0``, one per target: (best logit, logit
     of the target, argmax), each a numpy array, over blocks of rows."""
     head = params["embed"].T if dims.tied else params["lm_head"]
@@ -156,7 +158,7 @@ def head_stats(params, dims: Dims, x, row0: int, targets, quant=None):
     return tuple(np.concatenate(a) for a in out)
 
 
-def served_gaps(params, dims: Dims, prompt, output, *, control=False):
+def served_gaps(params, dims, prompt, output, *, control=False):
     """Teacher-forced over prompt + served tokens: at each served token,
     the reference's best logit minus its logit for the served token, and
     with ``control`` also minus its logit for the token the fp8 pass puts

@@ -3,7 +3,9 @@
   python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 From the root of a checkout. ``BENCHMARK.json`` names the cell; its
-configuration (``bench/configs/<config>.json``), traffic and deployment
+configuration (``bench/configs/<config>.json``), the architecture family
+that configuration names (``bench/families/<family>.py``: sizes,
+weights, reference, operation counts), traffic and deployment
 (``bench/traffic/<traffic>.json``), limits of the comparison
 (``bench/limits/<workload>.json``) and one reader per metric
 (``bench/metrics/<metric>.py``) are found by name. The system under test

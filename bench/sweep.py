@@ -46,7 +46,8 @@ def main(argv=None):
         t = copy.deepcopy(traffic)
         t["arrivals"]["rate"] = rate
         stamps, t0, compiles = rig.measure(t, args.seconds, args.seed)
-        w = Window(rig.dims, peaks, stamps, t0, args.seconds, 0.0)
+        w = Window(rig.family, rig.dims, peaks, stamps, t0, args.seconds,
+                   0.0)
         m = read_metrics([e for e in spec["end_to_end"]
                           if e["name"] != "setup_s"], cell, w)
         mid = backlog(stamps, t0 + args.seconds / 2)

@@ -18,10 +18,14 @@ LIMITS = {"sample": 4, "max_logit_gap": 0.02, "mean_logit_gap": 0.0005}
 
 
 def run(config: str, traffic: str, *, seed: int = 5, seconds: float = 1.5,
-        control: bool = False, sample: int = 4,
+        control: bool = False, sample: int = 4, family: str = "",
         tmp: Path = Path("/nonexistent")):
+    """One run of test configuration ``config`` under test traffic
+    ``traffic``; ``family`` names another family for the configuration."""
     from yardstick.cell import run_cell
     conf = json.loads((DATA / f"{config}.json").read_text())
+    if family:
+        conf["family"] = family
     traf = json.loads((DATA / f"{traffic}.json").read_text())
     return run_cell(cell={"name": f"{config}.{traffic}"}, spec=SPEC,
                     conf=conf, traffic=traf,

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from yardstick.model import Dims
+from yardstick.family import INTERFACE, family_of
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -32,8 +32,7 @@ def test_names_and_keys():
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda c: c["name"])
 def test_each_cell_finds_its_files(cell):
     conf = {c["name"]: c for c in SPEC["configs"]}[cell["config"]]
-    d = Dims.from_config(json.loads((ROOT / conf["file"]).read_text()))
-    assert d.layers > 0
+    assert (ROOT / conf["file"]).is_file()
     traffic = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json")
                          .read_text())
     assert traffic["arrivals"]["kind"] in ("poisson", "closed")
@@ -47,3 +46,15 @@ def test_each_cell_finds_its_files(cell):
     assert {m["name"] for m in mine} >= {"setup_s"}
     for m in mine:
         assert (BENCH / "metrics" / f"{m['name']}.py").is_file()
+
+
+@pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
+def test_each_configuration_resolves_its_family(config):
+    """The family its file names is a module of the whole interface, and
+    reads the file's sizes."""
+    conf = json.loads((ROOT / config["file"]).read_text())
+    fam = family_of(conf)
+    assert all(callable(getattr(fam, f)) for f in INTERFACE)
+    d = fam.dims(conf)
+    assert d.layers > 0 and d.vocab > 0 and d.kv_bytes_per_token > 0
+    hash(d)

@@ -5,7 +5,7 @@ import pytest
 from cpu_cell import run
 
 
-def test_altered_token_fails(monkeypatch):
+def alter_third_token(monkeypatch):
     """Every request's third token altered where the engine produces it."""
     from repro.serving.engine import Engine
     decode = Engine.decode_step
@@ -17,6 +17,11 @@ def test_altered_token_fails(monkeypatch):
                 out[s] = (out[s] + 1) % self.cfg.vocab_size
         return out
     monkeypatch.setattr(Engine, "decode_step", altered)
+
+
+def test_altered_token_fails(monkeypatch):
+    """Every request's third token altered where the engine produces it."""
+    alter_third_token(monkeypatch)
     r = run("qwen2-smoke", "disagg")
     assert not r["correct"], r["checked"]
 
@@ -42,4 +47,19 @@ def test_handoff_left_out_fails(monkeypatch):
     from repro.models import transformer as T
     monkeypatch.setattr(T, "scatter_blocks", lambda pool, ids, blocks: pool)
     r = run("qwen2-smoke", "disagg")
+    assert not r["correct"], r["checked"]
+
+
+@pytest.mark.parametrize("fault", ["altered_token", "kv_insert_left_out"])
+def test_colocated_dense_gqa_faults_fail(monkeypatch, fault):
+    """The same faults on the colocated deployment with QKV bias (the
+    qwen2.5-3b decode-heavy cell's path): a token altered where the engine
+    produces it, and each prompt's KV never written back by its insert."""
+    from repro.models import transformer as T
+    if fault == "altered_token":
+        alter_third_token(monkeypatch)
+    else:
+        monkeypatch.setattr(T, "scatter_blocks",
+                            lambda pool, ids, blocks: pool)
+    r = run("qwen2-smoke", "coloc")
     assert not r["correct"], r["checked"]

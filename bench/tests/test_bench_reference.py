@@ -27,7 +27,18 @@ def test_fp8_control_is_not_correct(seed):
     in the program's place) comes out not correct, on each number
     compared, at the same positions of the same served requests; the
     program passes the same limits."""
-    r = run("qwen2-wide", "disagg", seed=seed, seconds=3, sample=16,
+    control_fails_each_number("disagg", seed)
+
+
+@pytest.mark.parametrize("seed", [7, 8, 9])
+def test_fp8_control_is_not_correct_colocated(seed):
+    """The same on the colocated deployment (chunked prefill, the KV
+    round trip through insert, batched paged decode)."""
+    control_fails_each_number("coloc", seed)
+
+
+def control_fails_each_number(traffic, seed):
+    r = run("qwen2-wide", traffic, seed=seed, seconds=3, sample=16,
             control=True)
     prog, ctl, lim = r["program"]["checked"], r["checked"], r["limits"]
     assert r["program"]["correct"], prog

@@ -8,10 +8,10 @@ from pathlib import Path
 
 import pytest
 
-from yardstick import flops, xtrace
+from yardstick import xtrace
 from yardstick.cell import (Window, capacity_for, decode_window,
                              load_reader, percentile, warmup_requests)
-from yardstick.model import Dims
+from yardstick.family import load_family
 from yardstick.pacing import WallPaced, WallRecorder, WallStamps, WindowClosed
 from yardstick.traffic import open_loop_jobs, stratified_lengths
 
@@ -112,7 +112,7 @@ def test_ttft_and_itl_come_from_the_recorder_stamps():
     for t in (3.0, 3.5):
         clock[0] = t
         rec.on_decode_step(Eng, 0, 0, 2)
-    w = Window(None, {}, st, 1.0, 10.0, 7.0)
+    w = Window(None, None, {}, st, 1.0, 10.0, 7.0)
     assert load_reader("ttft_p90_s")(w) == pytest.approx(0.75)
     # gaps: 1.5 and 0.5 (request 0), 0.25 and 0.5 (request 1)
     assert load_reader("itl_p99_s")(w) == pytest.approx(1.5)
@@ -145,12 +145,12 @@ def test_trace_reduction_on_a_small_synthetic_trace():
     assert xtrace.top_ops(ops + [loop], 0.0, 8.0)[0] == ("fusion", 2.0)
 
 
-def dims(name):
-    return Dims.from_config(json.loads(
-        (BENCH / "configs" / f"{name}.json").read_text()))
-
-
 def test_counts_match_hand_counts():
+    flops = load_family("dense_gqa")
+
+    def dims(name):
+        return flops.dims(json.loads(
+            (BENCH / "configs" / f"{name}.json").read_text()))
     q25, q3 = dims("qwen2.5-3b"), dims("qwen3-14b-l10")
     # qwen3-14b: 5120 x 128 x (40 + 40 + 8 + 8) + 3 x 5120 x 17408
     assert flops.layer_matmul_params(q3) == 62_914_560 + 267_386_880

@@ -19,12 +19,10 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from yardstick import xtrace
-from yardstick.flops import decode_flops, decode_step_bytes, prefill_flops
-from yardstick.model import Dims, program_config
+from yardstick.family import family_of
 from yardstick.pacing import (WallPaced, WallRecorder, WallStamps,
                               WindowClosed, instrument)
 from yardstick.traffic import grid_lengths, rng_for
-from yardstick.weights import make_params
 
 BENCH = Path(__file__).resolve().parents[1]
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
@@ -59,11 +57,13 @@ def percentile(values: List[float], q: float) -> float:
 
 
 class Window:
-    """What the metric readers see of one run."""
+    """What the metric readers see of one run: the counts come from the
+    configuration's family (``yardstick/family.py``)."""
 
-    def __init__(self, dims: Dims, peaks: Dict, stamps: WallStamps,
+    def __init__(self, family, dims, peaks: Dict, stamps: WallStamps,
                  t0: float, seconds: float, setup_s: float):
-        self.dims, self.peaks, self.stamps = dims, peaks, stamps
+        self.family, self.dims = family, dims
+        self.peaks, self.stamps = peaks, stamps
         self.t0, self.seconds = t0, seconds
         self.t_end = t0 + seconds
         self.setup_s = setup_s
@@ -79,19 +79,19 @@ class Window:
                 if self.t0 <= d < end]
 
     def prefill_flops(self, prefills) -> float:
-        return float(sum(prefill_flops(self.dims, isl)
+        return float(sum(self.family.prefill_flops(self.dims, isl)
                          for _, _, isl in prefills))
 
     def decode_flops(self, decodes) -> float:
-        return float(sum(decode_flops(self.dims, c)
+        return float(sum(self.family.decode_flops(self.dims, c)
                          for _, _, ctx in decodes for c in ctx))
 
     def decode_bound_s(self, ctx) -> float:
         """Least time one decode step could take on this chip."""
-        p = self.peaks
-        return max(sum(decode_flops(self.dims, c) for c in ctx)
+        p, f = self.peaks, self.family
+        return max(sum(f.decode_flops(self.dims, c) for c in ctx)
                    / p["bf16_flops"],
-                   decode_step_bytes(self.dims, ctx) / p["hbm_bytes_per_s"])
+                   f.decode_step_bytes(self.dims, ctx) / p["hbm_bytes_per_s"])
 
 
 class TraceView:
@@ -264,13 +264,13 @@ def warm_up(cluster, jobs, vocab: int, seed: int):
                                f"{len(req.output)} tokens")
 
 
-def check_served(params, dims: Dims, done: Dict, limits: Dict, seed: int,
+def check_served(params, family, dims, done: Dict, limits: Dict, seed: int,
                  *, control: bool = False):
     """Compare a seeded sample of the finished requests, the longest among
-    them, with the reference. Returns the numbers compared for the
-    program and, with ``control``, the same numbers for the control put in
-    its place: at the same positions, the token the control puts first."""
-    from reference.dense import served_gaps
+    them, with the family's reference. Returns the numbers compared for
+    the program and, with ``control``, the same numbers for the control
+    put in its place: at the same positions, the token the control puts
+    first."""
     reqs = sorted(done.values(), key=lambda r: r.rid)
     short = sum(len(r.output) != r.osl for r in reqs)
     if not reqs:
@@ -283,7 +283,8 @@ def check_served(params, dims: Dims, done: Dict, limits: Dict, seed: int,
     sample = [longest] + [rest[i] for i in sorted(pick)]
     gaps, ctl = [], []
     for r in sample:
-        g, c = served_gaps(params, dims, r.prompt, r.output, control=control)
+        g, c = family.served_gaps(params, dims, r.prompt, r.output,
+                                  control=control)
         gaps.append(g)
         ctl.append(c)
 
@@ -319,9 +320,10 @@ class Rig:
                  log=print):
         import jax
         from repro.models import transformer as T
-        self.dims = dims = Dims.from_config(conf)
-        cfg = program_config(conf, dims)
-        self.params = make_params(dims, seed, conf["torch_dtype"])
+        self.family = family = family_of(conf)
+        self.dims = dims = family.dims(conf)
+        cfg = family.program_config(conf, dims)
+        self.params = family.make_params(dims, seed, conf["torch_dtype"])
         want = jax.tree.map(lambda a: (a.shape, a.dtype),
                             T.abstract_params(cfg))
         got = jax.tree.map(lambda a: (a.shape, a.dtype), self.params)
@@ -399,7 +401,8 @@ def run_cell(*, cell: Dict, spec: Dict, conf: Dict, traffic: Dict,
     rig = Rig(conf, traffic, seed, trace, log)
     tracer = Tracer(out_dir / "trace", 0.0, 0.0) if trace else None
     stamps, t0, compiles = rig.measure(traffic, seconds, seed, tracer, log)
-    w = Window(rig.dims, peaks, stamps, t0, seconds, t0 - t_start)
+    w = Window(rig.family, rig.dims, peaks, stamps, t0, seconds,
+               t0 - t_start)
     mem = (device.memory_stats() or {}).get("peak_bytes_in_use", 0) \
         if device is not None else 0
     if tracer is not None:
@@ -417,8 +420,8 @@ def run_cell(*, cell: Dict, spec: Dict, conf: Dict, traffic: Dict,
 
     # the program's state goes before the reference runs beside the weights
     rig.free_program()
-    prog, ctl = check_served(rig.params, rig.dims, stamps.done, limits, seed,
-                             control=control)
+    prog, ctl = check_served(rig.params, rig.family, rig.dims, stamps.done,
+                             limits, seed, control=control)
     lim = limits_of(limits)
     out = {"attempted": len(due), "failed": int(failed), "metrics": metrics,
            "device": dev, **extra, "limits": lim,
