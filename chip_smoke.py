@@ -8,7 +8,8 @@ line; a failed phase exits non-zero before the verdict is printed.
   device     a TPU must be present, and its ``device_kind`` must map to a
              ``core.hardware`` chip
   kernels    each Pallas kernel, compiled for the chip, against its
-             ``ref.py`` twin at real head widths
+             ``ref.py`` twin at real head widths (the latent paged kernel
+             at DeepSeek-V3's)
   disagg     ``repro.launch.serve`` with ``--full``: 1 prefill + 2 decode
              engines serve a burst of 8 requests (ISL 512, OSL 32)
   coloc      the same workload on one mixed pool of 3 engines
@@ -109,17 +110,20 @@ def _check(name, ok, err, tol):
 
 def phase_kernels(seed: int):
     """Each kernel once, compiled (interpret=False), at the widths the
-    registry gives qwen2.5-3b (H=16, Hkv=2, dh=128, bf16) and rwkv6-1.6b
-    (32 heads of 64, f32)."""
+    registry gives qwen2.5-3b (H=16, Hkv=2, dh=128, bf16), deepseek-v3
+    (128 heads over 640-lane latent rows, 512 value lanes, bf16) and
+    rwkv6-1.6b (32 heads of 64, f32)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import get_config
     from repro.kernels.decode_attention.ops import (decode_attention,
+                                                    decode_attention_mla,
                                                     decode_attention_paged)
     from repro.kernels.decode_attention.ref import (
-        decode_attention_paged_ref, decode_attention_ref)
+        decode_attention_mla_ref, decode_attention_paged_ref,
+        decode_attention_ref)
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.rwkv6.ops import wkv
@@ -183,6 +187,25 @@ def phase_kernels(seed: int):
     ref = decode_attention_paged_ref(f32(q), f32(pk), f32(pv), tables,
                                      lengths)
     lines.append(close("paged_decode", out, ref))
+
+    # latent paged decode (MLA, absorbed) over the same tables: one
+    # 640-lane row a token, its first 576 lanes used, its first 512 the
+    # values; 128 heads in the latent space
+    m_cfg = get_config("deepseek-v3")
+    Hm, lanes, used = m_cfg.num_heads, m_cfg.latent_lanes, m_cfg.latent_dim
+    pool = np.zeros((N, Bs, lanes), np.float32)
+    pool[..., :used] = randn(N, Bs, used)
+    qm = np.zeros((B, Hm, lanes), np.float32)
+    qm[..., :used] = randn(B, Hm, used)
+    qm, pool = bf(qm), bf(pool)
+    scale = 1.0 / np.sqrt(used)
+    out = decode_attention_mla(qm, pool, jnp.asarray(tables),
+                               jnp.asarray(lengths), scale=scale,
+                               value_lanes=m_cfg.kv_lora_rank)
+    ref = decode_attention_mla_ref(f32(qm), f32(pool), tables, lengths,
+                                   scale=scale,
+                                   value_lanes=m_cfg.kv_lora_rank)
+    lines.append(close("latent_decode", out, ref))
 
     # rwkv6 WKV recurrence: 4 chunks of 64 tokens
     Hr, Nr, S = r_cfg.num_heads, r_cfg.dh, 256

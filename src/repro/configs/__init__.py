@@ -17,6 +17,7 @@ _ARCH_MODULES = {
     "kimi-k2-1t-a32b": "repro.configs.kimi_k2_1t_a32b",
     "granite-moe-1b-a400m": "repro.configs.granite_moe_1b_a400m",
     "hymba-1.5b": "repro.configs.hymba_1_5b",
+    "deepseek-v3": "repro.configs.deepseek_v3",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

@@ -1,8 +1,13 @@
 """kimi-k2-1t-a32b [moe]: trillion-param MoE, 384 experts top-8.
 
-[arXiv:2501.kimi2 paper-table]. 61L d_model=7168 64H (GQA kv=8, head_dim=128)
-d_ff_expert=2048 vocab=163840, +1 shared expert. Trains with Adafactor +
-FSDP + grad accumulation (1T params; see DESIGN.md §8).
+[arXiv:2501.kimi2 paper-table]. 61L d_model=7168 64H, d_ff_expert=2048
+vocab=163840, +1 shared expert. Trains with Adafactor + FSDP + grad
+accumulation (1T params; see DESIGN.md §8).
+
+A GQA stand-in (kv=8, head_dim=128): the published config
+(https://huggingface.co/moonshotai/Kimi-K2-Instruct/blob/main/config.json)
+is DeepSeek-V3's block, with latent attention (MLA). ``deepseek-v3`` is
+the registry's MLA entry.
 """
 from repro.models.config import ModelConfig, MoEConfig
 

@@ -58,10 +58,13 @@ def perf_llm_from_config(cfg: "ModelConfig") -> PerfLLM:
         d_ff=cfg.d_ff,
         vocab_size=cfg.vocab_size,
         attention=("none" if cfg.block == "rwkv"
-                   else "hybrid" if cfg.block == "hybrid" else "gqa"),
+                   else "hybrid" if cfg.block == "hybrid"
+                   else "mla" if cfg.mla else "gqa"),
         num_experts=moe.num_experts if moe else 0,
         top_k=moe.top_k if moe else 0,
         d_ff_expert=moe.d_ff_expert if moe else 0,
         num_shared_experts=moe.num_shared_experts if moe else 0,
         sliding_window=cfg.sliding_window,
+        **({"mla_kv_rank": cfg.kv_lora_rank, "mla_rope_dim": cfg.qk_rope_dim}
+           if cfg.mla else {}),
     )

@@ -1,5 +1,5 @@
 """Jit'd wrappers: the dense split-KV kernel with its cross-split
-online-softmax merge, and the paged kernel."""
+online-softmax merge, and the paged kernels (GQA and latent)."""
 from __future__ import annotations
 
 import math
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_kernel)
 from repro.kernels.decode_attention.paged_decode import (
-    paged_decode_attention_kernel)
+    paged_decode_attention_kernel, paged_latent_decode_kernel)
 
 
 def _merge_splits(o, m, l):
@@ -63,3 +63,16 @@ def decode_attention_paged(q, pool_k, pool_v, tables, lengths, *,
         q.reshape(B, Hkv, G, dh), pool_k, pool_v, tables, lengths,
         scale=scale, interpret=interpret)
     return o.reshape(B, H, dh)
+
+
+@partial(jax.jit, static_argnames=("scale", "value_lanes", "interpret"))
+def decode_attention_mla(q, pool, tables, lengths, *, scale: float,
+                         value_lanes: int, interpret: bool = False):
+    """Paged latent (MLA, absorbed) decode attention. q: [B, H, lanes],
+    each head's query folded into the latent space; pool: [N, Bs, lanes]
+    latent rows [c_kv | k_pe]; tables: [B, nb] int32; lengths: [B] rows
+    to attend (>= 1). Returns [B, H, value_lanes]: the softmax-weighted
+    c_kv of each head (see paged_decode.py)."""
+    return paged_latent_decode_kernel(q, pool, tables, lengths, scale=scale,
+                                      value_lanes=value_lanes,
+                                      interpret=interpret)

@@ -20,6 +20,14 @@ normalized ``[Hkv, G, dh]`` output is written once per slot.
 
 Numerics follow the XLA decode path: bf16 K/V and q, scores and softmax
 in f32, ``p`` cast to the KV dtype before P.V with f32 accumulation.
+
+``paged_latent_decode_kernel`` is the same loop for latent attention
+(MLA, absorbed form). The pool is ``[N, Bs, R + rope]``: one latent row
+per token, shared by every head. Each live block is copied once, by one
+DMA, and serves as K (all of its lanes) and as V (its first R lanes, the
+c_kv part). All H query heads, already folded into the latent space, go
+against that one copy in the MXU's M dimension: scores ``[H, chunk]``,
+output ``[H, R]`` per slot.
 """
 from __future__ import annotations
 
@@ -186,3 +194,127 @@ def paged_decode_attention_kernel(q, pool_k, pool_v, tables, lengths, *,
         interpret=interpret,
     )(tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32), q,
       pool_k, pool_v)
+
+
+def _latent_kernel(tbl_ref, len_ref, q_ref, kv_hbm, o_ref, buf, sems,
+                   slot_ref, *, scale: float, nb: int, value_lanes: int):
+    b = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    _, P, Bs, lanes = buf.shape
+    H = q_ref.shape[1]
+    chunk = P * Bs
+
+    def live_blocks(s):
+        # a slot of length 0 still takes one chunk, all of it masked
+        return jnp.maximum(pl.cdiv(len_ref[s], Bs), 1)
+
+    def copy(s, c, i, slot):
+        return pltpu.make_async_copy(kv_hbm.at[tbl_ref[s * nb + c * P + i]],
+                                     buf.at[slot, i], sems.at[slot])
+
+    def n_live(s, c):
+        return jnp.minimum(live_blocks(s) - c * P, P)
+
+    def start(s, c, slot):
+        jax.lax.fori_loop(0, n_live(s, c),
+                          lambda i, _: copy(s, c, i, slot).start(), None)
+
+    def wait(s, c, slot):
+        n = n_live(s, c)
+
+        @pl.when(n == P)
+        def _full():
+            # the P copies signal one semaphore: one wait for the whole
+            # buffer's bytes covers them
+            pltpu.make_async_copy(kv_hbm.at[pl.ds(0, P)], buf.at[slot],
+                                  sems.at[slot]).wait()
+
+        @pl.when(n < P)
+        def _part():
+            jax.lax.fori_loop(0, n, lambda i, _: copy(s, c, i, slot).wait(),
+                              None)
+
+    @pl.when(b == 0)
+    def _first():
+        # rows past a slot's live blocks keep what an earlier chunk left:
+        # zero the buffers once, so those rows are finite and p = 0 there
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    length = len_ref[b]
+    nch = pl.cdiv(live_blocks(b), P)
+    q = q_ref[0]                                             # [H, lanes]
+
+    def body(c, state):
+        m_prev, l_prev, acc = state
+        cur = slot_ref[0]
+        nxt = 1 - cur
+        more = c + 1 < nch
+        ns = jnp.where(more, b, b + 1)
+        nc = jnp.where(more, c + 1, 0)
+
+        @pl.when(ns < nslots)
+        def _prefetch():
+            start(ns, nc, nxt)
+
+        wait(b, c, cur)
+        slot_ref[0] = nxt
+        kv = buf[cur].reshape(chunk, lanes)
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        cols = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (H, chunk), 1)
+        live = cols < length
+        s = jnp.where(live, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        pv = jax.lax.dot_general(p.astype(kv.dtype), kv[:, :value_lanes],
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return (m_new, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + pv)
+
+    state = (jnp.full((H, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, value_lanes), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, nch, body, state)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def paged_latent_decode_kernel(q, pool, tables, lengths, *, scale: float,
+                               value_lanes: int, interpret: bool = False):
+    """q: [B, H, lanes] (queries in the latent space); pool: [N, Bs,
+    lanes] latent rows; tables: [B, nb] int32 block ids in sequence
+    order; lengths: [B] int32 rows to attend, each at most nb*Bs. Keys are
+    whole rows, values their first ``value_lanes`` lanes. Returns
+    [B, H, value_lanes]."""
+    B, H, lanes = q.shape
+    _, Bs, pool_lanes = pool.shape
+    assert pool_lanes == lanes, "q and pool rows must have the same lanes"
+    nb = tables.shape[1]
+    P = chunk_blocks(Bs, lanes, pool.dtype.itemsize, nb)
+    kernel = functools.partial(_latent_kernel, scale=scale, nb=nb,
+                               value_lanes=value_lanes)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, lanes), lambda b, tbl, lens: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, value_lanes),
+                               lambda b, tbl, lens: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P, Bs, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_lanes), q.dtype),
+        # one slot's step prefetches the next slot's first chunk
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32), q,
+      pool)

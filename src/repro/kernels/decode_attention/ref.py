@@ -1,6 +1,7 @@
-"""Oracles for single-token GQA decode attention: pure-jnp for the dense
-split-KV kernel, pure-numpy for the paged kernel (no jax in the twin, so
-a ref mismatch can never share a bug with the implementation's stack)."""
+"""Oracles for single-token decode attention: pure-jnp for the dense
+split-KV kernel, pure-numpy for the paged kernels, GQA and latent (no jax
+in the twins, so a ref mismatch can never share a bug with the
+implementation's stack)."""
 import math
 
 import jax
@@ -48,3 +49,26 @@ def decode_attention_paged_ref(q, pool_k, pool_v, tables, lengths, *,
     e = np.where(mask, np.exp(s), 0.0)
     p = e / np.maximum(e.sum(axis=-1, keepdims=True), 1e-30)
     return np.einsum("bhk,bkhd->bhd", p, v)
+
+
+def decode_attention_mla_ref(q, pool, tables, lengths, *, scale,
+                             value_lanes):
+    """Pure-numpy twin of the latent paged kernel. q: [B,H,lanes]; pool:
+    [N,Bs,lanes] latent rows; tables: [B,nb]; lengths: [B]. Keys are whole
+    rows, values their first ``value_lanes`` lanes. -> [B,H,value_lanes]
+    (f32 math)."""
+    q = np.asarray(q, np.float32)
+    pool = np.asarray(pool, np.float32)
+    tables = np.asarray(tables)
+    lengths = np.asarray(lengths)
+    B = q.shape[0]
+    _, Bs, lanes = pool.shape
+    W = tables.shape[1] * Bs
+    rows = pool[tables].reshape(B, W, lanes)      # gather through the table
+    s = np.einsum("bhc,bwc->bhw", q, rows) * scale
+    mask = np.arange(W)[None, None, :] < lengths[:, None, None]
+    s = np.where(mask, s, -np.inf)
+    s = s - s.max(axis=-1, keepdims=True)
+    e = np.where(mask, np.exp(s), 0.0)
+    p = e / np.maximum(e.sum(axis=-1, keepdims=True), 1e-30)
+    return np.einsum("bhw,bwr->bhr", p, rows[..., :value_lanes])

@@ -8,7 +8,8 @@ are instances of the same substrate.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -24,6 +25,35 @@ class MoEConfig:
     # routing skew. Effective capacity = min(T, max(cf*T*k/E, min_capacity)).
     min_capacity: int = 8
     router_jitter: float = 0.0
+    # --- router: "softmax" (renormalised top-k of the softmax), or
+    # "sigmoid" (DeepSeek-V3's noaux_tc: sigmoid scores, a per-expert
+    # correction bias that steers the choice only, the top ``topk_group``
+    # of ``n_group`` groups by the sum of each group's best two, weights
+    # normalised and scaled by ``routed_scaling``) ---
+    router: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    # --- experts held by this device, as (first expert, count): the layer
+    # routes over all ``num_experts`` and computes, dropless, the part of
+    # the result its own experts give (an expert-parallel share without
+    # the exchange). None: every expert, on the capacity path ---
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_count(self) -> int:
+        return self.held[1] if self.held else self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V3's ``rope_scaling``, type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,9 +72,20 @@ class ModelConfig:
     qk_norm: bool = False          # qwen3
     rope_theta: float = 10_000.0
     sliding_window: int = 0        # 0 = full causal; >0 = SWA (hymba)
+    yarn: Optional[YarnConfig] = None
+    # --- latent attention (MLA, DeepSeek-V2/V3): on when kv_lora_rank > 0.
+    # q through a rank-q_lora_rank bottleneck (0: one projection); K/V
+    # through a cached latent of kv_lora_rank plus one shared rope key of
+    # qk_rope_dim; heads of qk_nope_dim + qk_rope_dim (q, k) and v_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # --- block composition ---
     block: str = "attn"            # attn | rwkv | hybrid
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0         # leading layers with a dense FFN (d_ff)
     # --- SSM (hybrid / mamba branch) ---
     ssm_state: int = 16
     ssm_conv: int = 4
@@ -75,6 +116,37 @@ class ModelConfig:
     @property
     def dh(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Lanes of one token's latent cache row: [c_kv | k_pe]."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes of a latent cache row as stored: ``latent_dim`` rounded up
+        to whole 128-lane tiles (a TPU lays a 576-lane row out as 640 in
+        HBM anyway, and its DMAs move whole tiles); the lanes past
+        ``latent_dim`` hold zeros."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def dense_layers(self) -> int:
+        """Leading layers with a dense FFN before the MoE layers."""
+        return min(self.first_k_dense, self.num_layers) if self.moe else 0
+
+    def yarn_mscale(self) -> float:
+        """YaRN's attention temperature 0.1 * mscale_all_dim * ln(factor)
+        + 1 (1 without YaRN): the softmax scale is multiplied by its
+        square."""
+        y = self.yarn
+        if y is None or y.factor <= 1 or not y.mscale_all_dim:
+            return 1.0
+        return 0.1 * y.mscale_all_dim * math.log(y.factor) + 1.0
 
     @property
     def q_group(self) -> int:
@@ -132,7 +204,18 @@ class ModelConfig:
             n += D * self.vocab_size                         # lm_head
         n += D                                               # final norm
         per_layer = 0
-        if self.block in ("attn", "hybrid"):
+        if self.mla:
+            H, R = self.num_heads, self.kv_lora_rank
+            qk = self.qk_nope_dim + self.qk_rope_dim
+            if self.q_lora_rank:
+                per_layer += (D + 1) * self.q_lora_rank          # q_a, norm
+                per_layer += self.q_lora_rank * H * qk           # q_b
+            else:
+                per_layer += D * H * qk
+            per_layer += D * self.latent_dim + R                 # kv_a, norm
+            per_layer += R * H * (self.qk_nope_dim + self.v_head_dim)
+            per_layer += H * self.v_head_dim * D + D             # wo, norm
+        elif self.block in ("attn", "hybrid"):
             per_layer += D * self.num_heads * dh             # wq
             per_layer += 2 * D * self.num_kv_heads * dh      # wk, wv
             per_layer += self.num_heads * dh * D             # wo
@@ -153,15 +236,19 @@ class ModelConfig:
             per_layer += 5 * D * D + 2 * D * 64 + 64 * D + 2 * D
             per_layer += D * int(3.5 * D) * 2 + D * D        # channel mix
             per_layer += 2 * D                               # two norms
+        dense_ffn = 3 * D * self.d_ff + D                    # swiglu, norm
         if self.moe is not None:
             m = self.moe
-            per_layer += D * m.num_experts                   # router
-            per_layer += m.num_experts * 3 * D * m.d_ff_expert
-            per_layer += m.num_shared_experts * 3 * D * m.d_ff_expert
-            per_layer += D                                   # ffn norm
-        elif self.block != "rwkv":
-            per_layer += 3 * D * self.d_ff                   # swiglu
-            per_layer += D                                   # ffn norm
+            moe_ffn = D * m.num_experts                      # router
+            if m.router == "sigmoid":
+                moe_ffn += m.num_experts                     # its bias
+            moe_ffn += m.held_count * 3 * D * m.d_ff_expert
+            moe_ffn += m.num_shared_experts * 3 * D * m.d_ff_expert
+            moe_ffn += D                                     # ffn norm
+            k = self.dense_layers
+            return n + L * per_layer + k * dense_ffn + (L - k) * moe_ffn
+        if self.block != "rwkv":
+            per_layer += dense_ffn
         return n + L * per_layer
 
     def active_param_count(self) -> int:
@@ -170,8 +257,10 @@ class ModelConfig:
             return self.param_count()
         m = self.moe
         total = self.param_count()
-        all_expert = self.num_layers * m.num_experts * 3 * self.d_model * m.d_ff_expert
-        active_expert = self.num_layers * (m.top_k + m.num_shared_experts) * (
+        moe_layers = self.num_layers - self.dense_layers
+        all_expert = (moe_layers * m.held_count * 3 * self.d_model
+                      * m.d_ff_expert)
+        active_expert = moe_layers * (m.top_k + m.num_shared_experts) * (
             3 * self.d_model * m.d_ff_expert)
         return total - all_expert + active_expert
 
@@ -179,6 +268,8 @@ class ModelConfig:
         """KV-cache bytes per token (for Eq 1/2 transfer analysis)."""
         if self.block == "rwkv":
             return 0  # O(1) state, not per-token
+        if self.mla:
+            return self.num_layers * self.latent_dim * bytes_element
         per_layer = 2 * self.num_kv_heads * self.dh * bytes_element
         return self.num_layers * per_layer
 

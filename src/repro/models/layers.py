@@ -44,13 +44,45 @@ def rope_frequencies(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=f32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float = 10_000.0):
-    """x: [..., S, H, dh]; positions: broadcastable to [..., S]."""
+def yarn_frequencies(head_dim: int, theta: float, yarn):
+    """YaRN's inverse frequencies and cos/sin scale (DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding``): frequencies interpolated by
+    ``factor`` where a dimension turns fewer than ``beta_slow`` times over
+    the original context, kept where it turns more than ``beta_fast``
+    times, and ramped linearly between."""
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    extra = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=f32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    keep = 1.0 - ramp
+    freqs = extra / yarn.factor * (1.0 - keep) + extra * keep
+
+    def mscale(m):
+        return 0.1 * m * math.log(yarn.factor) + 1.0 if yarn.factor > 1 \
+            else 1.0
+    return freqs, mscale(yarn.mscale) / mscale(yarn.mscale_all_dim)
+
+
+def apply_rope(x, positions, theta: float = 10_000.0, yarn=None):
+    """x: [..., S, H, dh]; positions: broadcastable to [..., S]. Rotates
+    the (first half, second half) pairs; ``yarn`` (a ``YarnConfig``)
+    scales the frequencies as YaRN does."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)                       # [dh/2]
+    if yarn is None:
+        freqs, ms = rope_frequencies(dh, theta), 1.0          # [dh/2]
+    else:
+        freqs, ms = yarn_frequencies(dh, theta, yarn)
     angles = positions[..., None].astype(f32) * freqs         # [..., S, dh/2]
     cos = jnp.cos(angles)[..., None, :]                       # [..., S, 1, dh/2]
     sin = jnp.sin(angles)[..., None, :]
+    if ms != 1.0:
+        cos, sin = cos * ms, sin * ms
     x1, x2 = jnp.split(x.astype(f32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

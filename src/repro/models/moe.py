@@ -14,6 +14,13 @@ move activations with gathers. Inside ``shard_map`` every model-shard:
 Tokens stay sharded over (pod, data); experts live on the model axis. No
 all-to-all is needed because activations are model-replicated at the FFN
 boundary (standard Megatron TP residual stream).
+
+A layer told which experts it holds (``MoEConfig.held``: first expert and
+count, e.g. chip 0 of DeepSeek-V3's EP32 holds experts 0-7) takes
+``held_moe`` instead: it routes every token over all experts and
+computes, dropless, the part of the routed sum its own experts give. On
+one device that part is the layer's result; the exchange that would add
+the other devices' parts is not run here.
 """
 from __future__ import annotations
 
@@ -37,12 +44,36 @@ def expert_capacity(num_tokens: int, cfg: MoEConfig) -> int:
     return int(min(num_tokens, max(c, cfg.min_capacity)))
 
 
-def _route(x, router_w, cfg: MoEConfig):
+def _sigmoid_topk(logits, bias, cfg: MoEConfig):
+    """DeepSeek-V3's ``noaux_tc`` choice: sigmoid scores s; the choice
+    reads s + bias; a group scores the sum of its best two choices, the
+    best ``topk_group`` groups stay and the others are masked to -inf (as
+    vLLM's ``grouped_topk``; HF masks to 0); the top-k of what is left.
+    Weights are s there, normalised to sum 1, times ``routed_scaling``.
+    Returns (weights [T,k] f32, expert ids [T,k])."""
+    T, E = logits.shape
+    s = jax.nn.sigmoid(logits)
+    choice = s + bias.astype(f32)[None, :]
+    G = cfg.n_group
+    group = jnp.sum(jax.lax.top_k(choice.reshape(T, G, E // G), 2)[0], -1)
+    _, keep = jax.lax.top_k(group, cfg.topk_group)              # [T,g]
+    kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.int32), axis=1) > 0
+    choice = jnp.where(jnp.repeat(kept, E // G, axis=1), choice, -jnp.inf)
+    _, ids = jax.lax.top_k(choice, cfg.top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling, ids
+
+
+def _route(x, router_w, cfg: MoEConfig, bias=None):
     """Returns (gates [T,E] dense fp32, mask [T,E] int32, aux metrics)."""
     logits = jnp.einsum("td,de->te", x.astype(f32), router_w.astype(f32))
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_ids = jax.lax.top_k(probs, cfg.top_k)            # [T,k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)      # renorm
+    if cfg.router == "sigmoid":
+        top_p, top_ids = _sigmoid_topk(logits, bias, cfg)
+    else:
+        top_p, top_ids = jax.lax.top_k(probs, cfg.top_k)        # [T,k]
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # renorm
     mask = jnp.sum(jax.nn.one_hot(top_ids, cfg.num_experts, dtype=jnp.int32),
                    axis=1)                                      # [T,E]
     gates = jnp.zeros_like(probs).at[
@@ -58,9 +89,30 @@ def _route(x, router_w, cfg: MoEConfig):
     return gates, mask, aux, z
 
 
+def held_moe(x, params, cfg: MoEConfig):
+    """x: [T, D]. The routed-expert sum over this device's held experts
+    (``cfg.held``), dropless: every held expert runs over every token, a
+    token off its top-k with a zero gate, so no token is ever dropped
+    and the shape does not depend on the routing. Returns (y [T, D] f32,
+    hits [T, E_held] bool: which held experts each token was routed
+    to)."""
+    off, n = cfg.held
+    gates, mask, _, _ = _route(x, params["router"], cfg,
+                               params.get("router_bias"))
+    g = gates[:, off:off + n]                                    # [T,n]
+    h = jnp.einsum("td,edf->etf", x, params["wg"])
+    u = jnp.einsum("td,edf->etf", x, params["wu"])
+    h = jax.nn.silu(h.astype(f32)).astype(x.dtype) * u
+    ye = jnp.einsum("etf,efd->etd", h, params["wd"],
+                    preferred_element_type=f32)
+    y = jnp.einsum("etd,te->td", ye, g)
+    return y, mask[:, off:off + n] > 0
+
+
 def _local_moe(x, router_w, wg, wu, wd, *, cfg: MoEConfig,
                ep_axis: Optional[str], dp_axes: Tuple[str, ...],
-               combine_fp32: bool = True, tp_axes: Tuple[str, ...] = ()):
+               combine_fp32: bool = True, tp_axes: Tuple[str, ...] = (),
+               router_bias=None):
     """x: [T, D] local tokens; wg/wu/wd: [E_local, D, H(/tp)] expert shards.
 
     tp_axes non-empty = expert-TP serving mode: the expert hidden dim is
@@ -71,7 +123,7 @@ def _local_moe(x, router_w, wg, wu, wd, *, cfg: MoEConfig,
     E_local = wg.shape[0]
     C = expert_capacity(T, cfg)
 
-    gates, mask, aux_loss, z_loss = _route(x, router_w, cfg)
+    gates, mask, aux_loss, z_loss = _route(x, router_w, cfg, router_bias)
 
     # position of token t in expert e's buffer (cumsum over tokens)
     pos = jnp.cumsum(mask, axis=0) - 1                           # [T,E]
@@ -154,6 +206,15 @@ def moe_ffn(x, params, cfg: MoEConfig, *, combine_fp32: bool = True,
                      and (B * S) % dp_size == 0 and B % dp_size == 0)
     xf = x.reshape(B * S, D)
 
+    if cfg.held is not None:
+        with jax.named_scope("moe"):
+            y, hits = held_moe(xf, params, cfg)
+            y = y.astype(x.dtype).reshape(B, S, D)
+            if cfg.num_shared_experts:
+                from repro.models.layers import swiglu
+                y = y + swiglu(x, params["shared_wg"], params["shared_wu"],
+                               params["shared_wd"])
+        return y, {"held_hits": hits}
     if use_expert_tp:
         fn = partial(_local_moe, cfg=cfg, ep_axis=rules.ep[0], dp_axes=(),
                      combine_fp32=combine_fp32, tp_axes=rules.dp)
@@ -188,7 +249,8 @@ def moe_ffn(x, params, cfg: MoEConfig, *, combine_fp32: bool = True,
     else:
         y, aux = _local_moe(xf, params["router"], params["wg"], params["wu"],
                             params["wd"], cfg=cfg, ep_axis=None, dp_axes=(),
-                            combine_fp32=combine_fp32)
+                            combine_fp32=combine_fp32,
+                            router_bias=params.get("router_bias"))
 
     y = y.reshape(B, S, D)
 

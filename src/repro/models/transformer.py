@@ -31,7 +31,36 @@ f32 = jnp.float32
 # Init
 
 
+def _init_mla_layer(key, cfg: ModelConfig):
+    """Latent attention weights, laid out as DeepSeek-V3's projections:
+    q_a [D, q_lora] and q_b [q_lora, H, nope+rope] (or wq [D, H, nope+rope]
+    without q_lora), kv_a [D, kv_lora+rope], kv_b [kv_lora, H, nope+v]
+    (each head's K-nope columns, then its V columns), o [H, v, D]."""
+    D, H, R = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    qk, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 5)
+    dt = cfg.jdtype
+
+    def normal(k, shape, fan_in):
+        return (jax.random.normal(k, shape, f32)
+                / math.sqrt(fan_in)).astype(dt)
+    p = {"attn_norm": jnp.ones((D,), dt),
+         "wkv_a": normal(ks[2], (D, cfg.latent_dim), D),
+         "kv_a_norm": jnp.ones((R,), dt),
+         "wkv_b": normal(ks[3], (R, H, cfg.qk_nope_dim + dv), R),
+         "wo": normal(ks[4], (H, dv, D), H * dv * cfg.num_layers)}
+    if cfg.q_lora_rank:
+        p["wq_a"] = normal(ks[0], (D, cfg.q_lora_rank), D)
+        p["q_a_norm"] = jnp.ones((cfg.q_lora_rank,), dt)
+        p["wq_b"] = normal(ks[1], (cfg.q_lora_rank, H, qk), cfg.q_lora_rank)
+    else:
+        p["wq"] = normal(ks[0], (D, H, qk), D)
+    return p
+
+
 def _init_attn_layer(key, cfg: ModelConfig):
+    if cfg.mla:
+        return _init_mla_layer(key, cfg)
     D, dh = cfg.d_model, cfg.dh
     Hkv = cfg.padded_kv_heads
     Hp = cfg.padded_heads
@@ -65,17 +94,20 @@ def _init_ffn_layer(key, cfg: ModelConfig):
         m = cfg.moe
         ks = jax.random.split(key, 7)
         sh = 1.0 / math.sqrt(D)
+        E = m.held_count
         p["moe"] = {
             "router": (jax.random.normal(ks[0], (D, m.num_experts), f32)
                        * 0.02).astype(f32),
-            "wg": (jax.random.normal(ks[1], (m.num_experts, D, m.d_ff_expert),
+            "wg": (jax.random.normal(ks[1], (E, D, m.d_ff_expert),
                                      f32) * sh).astype(dt),
-            "wu": (jax.random.normal(ks[2], (m.num_experts, D, m.d_ff_expert),
+            "wu": (jax.random.normal(ks[2], (E, D, m.d_ff_expert),
                                      f32) * sh).astype(dt),
-            "wd": (jax.random.normal(ks[3], (m.num_experts, m.d_ff_expert, D),
+            "wd": (jax.random.normal(ks[3], (E, m.d_ff_expert, D),
                                      f32) * sh / math.sqrt(cfg.num_layers)
                    ).astype(dt),
         }
+        if m.router == "sigmoid":
+            p["moe"]["router_bias"] = jnp.zeros((m.num_experts,), f32)
         if m.num_shared_experts:
             F = m.d_ff_expert * m.num_shared_experts
             p["moe"]["shared_wg"] = (jax.random.normal(ks[4], (D, F), f32)
@@ -112,12 +144,11 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     D, V = cfg.d_model, cfg.padded_vocab
     dt = cfg.jdtype
     layer_keys = jax.random.split(kL, cfg.num_layers)
-    blocks = jax.vmap(partial(_init_layer, cfg=cfg))(layer_keys)
-    p = {
-        "embed": (jax.random.normal(kE, (V, D), f32) * 0.02).astype(dt),
-        "blocks": blocks,
-        "final_norm": jnp.ones((D,), dt),
-    }
+    p = {"embed": (jax.random.normal(kE, (V, D), f32) * 0.02).astype(dt)}
+    for name, lcfg, lo, n in layer_stacks(cfg):
+        p[name] = jax.vmap(partial(_init_layer, cfg=lcfg))(
+            layer_keys[lo:lo + n] if n != cfg.num_layers else layer_keys)
+    p["final_norm"] = jnp.ones((D,), dt)
     if not cfg.tie_embeddings:
         p["lm_head"] = (jax.random.normal(kH, (D, V), f32)
                         / math.sqrt(D)).astype(dt)
@@ -373,13 +404,13 @@ def attn_decode(p, x1, cfg: ModelConfig, k_cache, v_cache, pos,
             (k_cache, v_cache, new_scales))
 
 
-def _chunk_attend(q, kr, vr, mask, cfg: ModelConfig):
+def _chunk_attend(q, kr, vr, mask, cfg: ModelConfig, scale=None):
     """Mask-based chunk-attention core shared by the dense and paged
     prefill paths: q [B,Sq,H,dh] against *expanded* kr/vr [B,C,H,dh] with
     causal mask [Sq,C]. Shared for the same reason as ``_decode_attend``:
     identical float ops keep dense and paged prefill logits bit-equal."""
     with jax.named_scope("attention"):
-        scale = 1.0 / math.sqrt(cfg.dh)
+        scale = 1.0 / math.sqrt(cfg.dh) if scale is None else scale
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
                        preferred_element_type=f32) * scale
         s = jnp.where(mask[None, None], s, L.NEG_INF)
@@ -413,6 +444,132 @@ def attn_chunk(p, x, cfg: ModelConfig, k_cache, v_cache, kv_offset):
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3)
+#
+# A token's cache row is its latent [rms_norm(c_kv) | rope(k_pe) | 0]:
+# kv_lora_rank + qk_rope_dim lanes, zero-padded to whole 128-lane tiles
+# (``latent_lanes``), one row for every head. Prefill expands
+# the latent rows through kv_b into per-head K and V and attends as MHA.
+# Decode attends in the absorbed form over the rows themselves: each
+# head's q_nope folds through its W_UK (kv_b's K columns) into the latent
+# space, scores are q_lat . c_kv + q_pe . k_pe, and the softmax-weighted
+# c_kv unfolds through its W_UV (kv_b's V columns). Rope is rotate-half
+# over the rope lanes; the published checkpoints store those lanes
+# interleaved, a fixed permutation of q_b's and kv_a's rope columns.
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-1/2, times YaRN's mscale squared."""
+    return cfg.yarn_mscale() ** 2 / math.sqrt(cfg.qk_nope_dim
+                                              + cfg.qk_rope_dim)
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    return L.apply_rope(x, positions, cfg.rope_theta, yarn=cfg.yarn)
+
+
+def _mla_q(p, xn, cfg: ModelConfig, positions):
+    """-> (q_nope [B,S,H,nope], roped q_pe [B,S,H,rope])."""
+    if cfg.q_lora_rank:
+        qa = jnp.einsum("bsd,dr->bsr", xn, p["wq_a"])
+        qa = L.rms_norm(qa, p["q_a_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", qa, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", xn, p["wq"])
+    n = cfg.qk_nope_dim
+    return q[..., :n], _mla_rope(q[..., n:], positions, cfg)
+
+
+def _mla_latent(p, xn, cfg: ModelConfig, positions):
+    """Each token's cache row [B,S,latent_lanes]."""
+    R = cfg.kv_lora_rank
+    ckv = jnp.einsum("bsd,dr->bsr", xn, p["wkv_a"])
+    c = L.rms_norm(ckv[..., :R], p["kv_a_norm"], cfg.norm_eps)
+    kpe = _mla_rope(ckv[..., None, R:], positions, cfg)[..., 0, :]
+    pad = jnp.zeros(c.shape[:-1] + (cfg.latent_lanes - cfg.latent_dim,),
+                    c.dtype)
+    return jnp.concatenate([c, kpe.astype(c.dtype), pad], axis=-1)
+
+
+def _mla_expand(p, lat, cfg: ModelConfig):
+    """Latent rows [B,W,latent_lanes] -> per-head K [B,W,H,nope+rope] and
+    V [B,W,H,v]: kv_b over c_kv, and k_pe shared by the heads."""
+    R, n = cfg.kv_lora_rank, cfg.qk_nope_dim
+    kv = jnp.einsum("bwr,rhk->bwhk", lat[..., :R], p["wkv_b"])
+    kpe = jnp.broadcast_to(lat[:, :, None, R:cfg.latent_dim],
+                           kv.shape[:3] + (cfg.qk_rope_dim,))
+    return jnp.concatenate([kv[..., :n], kpe], axis=-1), kv[..., n:]
+
+
+def mla_full(p, x, cfg: ModelConfig):
+    """Full causal latent attention over x, expanded. Returns (attn_out,
+    latent rows [B,S,latent_lanes])."""
+    B, S, _ = x.shape
+    positions = jnp.arange(S)[None, :]
+    qn, qr = _mla_q(p, x, cfg, positions)
+    lat = _mla_latent(p, x, cfg, positions)
+    k, v = _mla_expand(p, lat, cfg)
+    q = jnp.concatenate([qn, qr], axis=-1)
+    dv = v.shape[-1]
+    with jax.named_scope("attention"):
+        # the blocked XLA core takes one head size: V is zero-padded to
+        # the q/k size and the padding sliced off the output
+        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - dv)))
+        o = L.causal_attention_xla(q, k, vp, scale=_mla_scale(cfg))
+    return _attn_out(p, o[..., :dv].astype(x.dtype), cfg), lat
+
+
+def _mla_absorb(p, qn, qr, cfg: ModelConfig):
+    """q_nope [B,H,nope], q_pe [B,H,rope] -> the query in the latent space
+    [B,H,latent_lanes]: each head's q_nope through its W_UK."""
+    w_uk = p["wkv_b"][..., :cfg.qk_nope_dim]                 # [R,H,nope]
+    ql = jnp.einsum("bhk,rhk->bhr", qn, w_uk, preferred_element_type=f32)
+    pad = jnp.zeros(qr.shape[:-1] + (cfg.latent_lanes - cfg.latent_dim,),
+                    qn.dtype)
+    return jnp.concatenate([ql.astype(qn.dtype), qr, pad], axis=-1)
+
+
+def _mla_unabsorb(p, ol, cfg: ModelConfig):
+    """Softmax-weighted c_kv [B,H,kv_lora] -> [B,1,H,v] through each
+    head's W_UV."""
+    w_uv = p["wkv_b"][..., cfg.qk_nope_dim:]                 # [R,H,v]
+    o = jnp.einsum("bhr,rhk->bhk", ol.astype(w_uv.dtype), w_uv,
+                   preferred_element_type=f32)
+    return o[:, None]
+
+
+def _mla_decode_attend(q, lat, valid, cfg: ModelConfig):
+    """Absorbed single-token core shared by the dense and paged layouts:
+    q [B,H,latent_lanes] against latent rows [B,W,latent_lanes] with
+    validity mask [B,W]; each row is K whole and V in its first kv_lora
+    lanes. Returns [B,H,kv_lora] f32."""
+    with jax.named_scope("attention"):
+        s = jnp.einsum("bhc,bwc->bhw", q, lat,
+                       preferred_element_type=f32) * _mla_scale(cfg)
+        s = jnp.where(valid[:, None, :], s, L.NEG_INF)
+        pr = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhw,bwr->bhr", pr.astype(lat.dtype),
+                       lat[..., :cfg.kv_lora_rank],
+                       preferred_element_type=f32)
+    return o
+
+
+def mla_decode(p, x1, cfg: ModelConfig, cache, pos):
+    """x1: [B,1,D] (normed); cache [B,C,latent_lanes]; pos [B]. Writes
+    this token's row at pos, then attends over rows [0, pos]."""
+    B, C = cache.shape[:2]
+    qn, qr = _mla_q(p, x1, cfg, pos[:, None])
+    lat = _mla_latent(p, x1, cfg, pos[:, None])
+    cache = cache.at[jnp.arange(B), jnp.minimum(pos, C - 1)].set(
+        lat[:, 0].astype(cache.dtype))
+    valid = jnp.arange(C)[None, :] <= pos[:, None]
+    ol = _mla_decode_attend(_mla_absorb(p, qn[:, 0], qr[:, 0], cfg), cache,
+                            valid, cfg)
+    o = _mla_unabsorb(p, ol, cfg)
+    return _attn_out(p, o.astype(x1.dtype), cfg), cache
+
+
+# ---------------------------------------------------------------------------
 # FFN dispatch
 
 
@@ -435,8 +592,10 @@ def _zero_aux():
 
 
 def _pad_aux(aux):
+    """The three summed training metrics (a held-expert layer's routing
+    hits are the decode step's, not training's)."""
     out = _zero_aux()
-    out.update(aux)
+    out.update({k: v for k, v in aux.items() if k in out})
     return out
 
 
@@ -451,7 +610,10 @@ def _layer_train(p, x, cfg: ModelConfig, impl: str):
         x, _ = rwkv6.rwkv_block(p, x, state, cfg)
         return x, _zero_aux()
     xn = L.rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    attn_out, _ = attn_full(p, xn, cfg, impl=impl)
+    if cfg.mla:
+        attn_out, _ = mla_full(p, xn, cfg)
+    else:
+        attn_out, _ = attn_full(p, xn, cfg, impl=impl)
     if cfg.block == "hybrid":
         ssm_out, _ = ssm.ssm_apply(p["ssm"], xn, ssm.init_ssm_state(cfg, x.shape[0]), cfg)
         y = 0.5 * (L.rms_norm(attn_out, p["attn_out_norm"], cfg.norm_eps)
@@ -471,6 +633,10 @@ def _layer_prefill(p, x, cfg: ModelConfig, impl: str):
         x, new_state = rwkv6.rwkv_block(p, x, state, cfg)
         return x, new_state
     xn = L.rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    if cfg.mla:
+        attn_out, lat = mla_full(p, xn, cfg)
+        x, _ = _ffn(p, x + attn_out, cfg)
+        return x, {"kv": lat}
     attn_out, (k, v) = attn_full(p, xn, cfg, impl=impl)
     entry = {}
     if cfg.block == "hybrid":
@@ -508,6 +674,10 @@ def _layer_decode(p, x1, cfg: ModelConfig, entry, pos):
         x1, new_state = rwkv6.rwkv_block_step(p, x1, entry, cfg)
         return x1, new_state
     xn = L.rms_norm(x1, p["attn_norm"], cfg.norm_eps)
+    if cfg.mla:
+        attn_out, kv = mla_decode(p, xn, cfg, entry["kv"], pos)
+        x1, _ = _ffn(p, x1 + attn_out, cfg)
+        return x1, {"kv": kv}
     scales = ({"k_scale": entry["k_scale"], "v_scale": entry["v_scale"]}
               if cfg.kv_quant else None)
     attn_out, (k_c, v_c, new_scales) = attn_decode(
@@ -527,6 +697,48 @@ def _layer_decode(p, x1, cfg: ModelConfig, entry, pos):
 
 
 # ---------------------------------------------------------------------------
+# Layer stacks: the layer loop is one lax.scan per stack of identical layers
+
+
+def layer_stacks(cfg: ModelConfig):
+    """The stacks of identical layers, in order, as (params key, layer
+    config, first layer, count). Leading dense layers of an MoE model
+    (``first_k_dense``) are a stack of their own, ``dense_blocks``, run
+    with the config's dense FFN; every other config is one stack,
+    ``blocks``, and traces one scan as it always has."""
+    k = cfg.dense_layers
+    if not k:
+        return (("blocks", cfg, 0, cfg.num_layers),)
+    return (("dense_blocks", cfg.replace(moe=None), 0, k),
+            ("blocks", cfg, k, cfg.num_layers - k))
+
+
+def scan_layers(body, carry, params, cfg: ModelConfig, xs=None, *,
+                remat: bool = False):
+    """``lax.scan`` of ``body(layer_cfg, carry, (layer params, xs row))``
+    over each stack in turn. ``xs`` has a leading layer axis (or is None).
+    Returns (carry, [each stack's stacked outputs])."""
+    stacks = layer_stacks(cfg)
+    ys = []
+    for name, lcfg, lo, n in stacks:
+        fn = partial(body, lcfg)
+        if remat:
+            fn = jax.checkpoint(fn)
+        rows = xs if len(stacks) == 1 else jax.tree.map(
+            lambda a: a[lo:lo + n], xs)
+        carry, y = jax.lax.scan(fn, carry, (params[name], rows))
+        ys.append(y)
+    return carry, ys
+
+
+def _layers_concat(ys):
+    """Per-stack outputs joined along the layer axis."""
+    if len(ys) == 1:
+        return ys[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *ys)
+
+
+# ---------------------------------------------------------------------------
 # Model-level entry points
 
 
@@ -534,13 +746,14 @@ def forward_hidden(params, cfg: ModelConfig, inputs, *, impl="xla"):
     """Training-mode forward to final hidden states. Returns (x, mask, aux)."""
     x, mask = embed_inputs(params, cfg, inputs)
 
-    def body(carry, layer_p):
+    def body(lcfg, carry, inp):
+        layer_p, _ = inp
         xc, aux_acc = carry
-        xc, aux = _layer_train(layer_p, xc, cfg, impl)
+        xc, aux = _layer_train(layer_p, xc, lcfg, impl)
         return (xc, jax.tree.map(jnp.add, aux_acc, aux)), None
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), _ = jax.lax.scan(body_fn, (x, _zero_aux()), params["blocks"])
+    (x, aux), _ = scan_layers(body, (x, _zero_aux()), params, cfg,
+                              remat=cfg.remat)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     aux = {k: v / cfg.num_layers for k, v in aux.items()}
     return x, mask, aux
@@ -579,6 +792,9 @@ def init_cache(cfg: ModelConfig, batch: int, capacity: int):
             "pos": jnp.zeros((B,), jnp.int32),
         }
     C = cfg.sliding_window if cfg.sliding_window else capacity
+    if cfg.mla:
+        return {"kv": jnp.zeros((Lr, B, C, cfg.latent_lanes), dt),
+                "pos": jnp.zeros((B,), jnp.int32)}
     Hkvp = cfg.padded_kv_heads
     kv_dt = jnp.int8 if cfg.kv_quant else dt
     cache = {
@@ -603,6 +819,8 @@ def abstract_cache(cfg: ModelConfig, batch: int, capacity: int):
 def _cache_keys(cfg: ModelConfig):
     if cfg.block == "rwkv":
         return ("s", "tm_x", "cm_x")
+    if cfg.mla:
+        return ("kv",)
     keys = ("k", "v") + (("k_scale", "v_scale") if cfg.kv_quant else ())
     if cfg.block == "hybrid":
         keys = keys + ("ssm_h", "conv")
@@ -616,12 +834,13 @@ def prefill_full(params, cfg: ModelConfig, inputs, *, capacity: Optional[int] = 
     B, S, _ = emb.shape
     capacity = capacity or S
 
-    def body(xc, layer_p):
-        xc, entry = _layer_prefill(layer_p, xc, cfg, impl)
+    def body(lcfg, xc, inp):
+        layer_p, _ = inp
+        xc, entry = _layer_prefill(layer_p, xc, lcfg, impl)
         return xc, entry
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, entries = jax.lax.scan(body_fn, emb, params["blocks"])
+    x, entries = scan_layers(body, emb, params, cfg, remat=cfg.remat)
+    entries = _layers_concat(entries)
     with jax.named_scope("head"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -634,8 +853,7 @@ def prefill_full(params, cfg: ModelConfig, inputs, *, capacity: Optional[int] = 
     if cfg.block == "attn":
         # grow cache to requested capacity
         if capacity > S:
-            for key in ("k", "v") + (("k_scale", "v_scale")
-                                     if cfg.kv_quant else ()):
+            for key in _cache_keys(cfg):
                 pad = jnp.zeros(cache[key].shape[:2] + (capacity - S,)
                                 + cache[key].shape[3:], cache[key].dtype)
                 cache[key] = jnp.concatenate([cache[key], pad], axis=2)
@@ -649,12 +867,13 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
     pos = cache["pos"]
     entries = {k: cache[k] for k in _cache_keys(cfg)}
 
-    def body(x1, inp):
+    def body(lcfg, x1, inp):
         layer_p, entry = inp
-        x1, new_entry = _layer_decode(layer_p, x1, cfg, entry, pos)
+        x1, new_entry = _layer_decode(layer_p, x1, lcfg, entry, pos)
         return x1, new_entry
 
-    x, new_entries = jax.lax.scan(body, x, (params["blocks"], entries))
+    x, new_entries = scan_layers(body, x, params, cfg, entries)
+    new_entries = _layers_concat(new_entries)
     with jax.named_scope("head"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -683,6 +902,8 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
     """
     assert cfg.block == "attn", "chunked prefill: attn family only"
     assert not cfg.kv_quant, "chunked prefill path keeps bf16 KV"
+    assert not cfg.mla and not cfg.dense_layers, \
+        "latent or mixed-layer models prefill in chunks on the paged layout"
     emb, _ = embed_inputs(params, cfg, inputs)
     B, S, D = emb.shape
     capacity = capacity or S
@@ -728,6 +949,7 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
 #
 # Layout: one pool of KV blocks shared by every layer and request,
 #   pool = {"k", "v": [num_blocks, block_size, Hkvp*dh]}
+# (latent attention: pool = {"kv": [num_blocks, block_size, latent_lanes]})
 # with per-request *per-layer* block tables [L, nb] (int32 block ids).
 # Host-side ownership/refcounts live in serving/blocks.py; everything here
 # is the pure compute: decode gathers K/V through the table, prefill
@@ -738,39 +960,42 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
 
 
 def supports_paged(cfg: ModelConfig) -> bool:
-    """Paged serving covers the dense-attention family. Quantized KV keeps
-    per-slot scale planes and sliding-window keeps a ring layout; both fall
-    back to the dense per-slot cache (as do rwkv/hybrid recurrent states,
-    which have no KV growth to page)."""
+    """Paged serving covers the attention family, latent (MLA) included.
+    Quantized KV keeps per-slot scale planes and sliding-window keeps a
+    ring layout; both fall back to the dense per-slot cache (as do
+    rwkv/hybrid recurrent states, which have no KV growth to page)."""
     return (cfg.block == "attn" and not cfg.kv_quant
             and not cfg.sliding_window)
 
 
 def init_block_pool(cfg: ModelConfig, num_blocks: int, block_size: int):
-    """Zero-filled block pool {"k","v": [N, Bs, Hkvp*dh]}: a token's kv
-    heads lie side by side in lanes, so one block is Bs lane-dense rows,
-    the layout the paged decode kernel reads in place."""
+    """Zero-filled block pool: {"k","v": [N, Bs, Hkvp*dh]} (a token's kv
+    heads lie side by side in lanes), or for latent attention one
+    {"kv": [N, Bs, latent_lanes]} (a token's latent row). Either way one
+    block is Bs lane-dense rows, the layout the paged decode kernels read
+    in place."""
+    if cfg.mla:
+        return {"kv": jnp.zeros((num_blocks, block_size, cfg.latent_lanes),
+                                cfg.jdtype)}
     shape = (num_blocks, block_size, cfg.padded_kv_heads * cfg.dh)
     return {"k": jnp.zeros(shape, cfg.jdtype),
             "v": jnp.zeros(shape, cfg.jdtype)}
 
 
 def gather_blocks(pool, ids):
-    """ids [L, nb] -> free-floating block tensors
-    {"k","v": [L, nb, Bs, Hkvp*dh]} — the paged KV-handoff payload (only
-    the request's own blocks travel, never the whole pool)."""
-    return {"k": pool["k"][ids], "v": pool["v"][ids]}
+    """ids [L, nb] -> free-floating block tensors, one [L, nb, Bs, lanes]
+    per pool array — the paged KV-handoff payload (only the request's own
+    blocks travel, never the whole pool)."""
+    return {k: a[ids] for k, a in pool.items()}
 
 
 def scatter_blocks(pool, ids, blocks):
     """Write handoff block tensors into the destination pool at ids [L, nb]
     (ids are distinct across layers: each layer owns its blocks)."""
     flat = ids.reshape(-1)
-    bk = blocks["k"]
-    shp = (-1,) + tuple(bk.shape[2:])
-    return {"k": pool["k"].at[flat].set(bk.reshape(shp).astype(pool["k"].dtype)),
-            "v": pool["v"].at[flat].set(
-                blocks["v"].reshape(shp).astype(pool["v"].dtype))}
+    return {k: a.at[flat].set(blocks[k].reshape((-1,) + a.shape[1:])
+                              .astype(a.dtype))
+            for k, a in pool.items()}
 
 
 def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
@@ -811,27 +1036,65 @@ def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
     return _attn_out(p, o.astype(x1.dtype), cfg), (pool_k, pool_v)
 
 
+def mla_decode_paged(p, x1, cfg: ModelConfig, pool_kv, tbl, pos,
+                     impl="xla"):
+    """``attn_decode_paged`` for latent attention: writes this token's
+    latent row into the slot's current block of pool_kv [N,Bs,latent_lanes]
+    and attends in the absorbed form over the slot's first pos+1 rows.
+    ``impl="pallas"`` runs the latent paged kernel, which copies each live
+    block once and reads it as K (every lane) and V (the c_kv lanes);
+    ``"xla"`` gathers the window and runs ``_mla_decode_attend``."""
+    B, Bs = x1.shape[0], pool_kv.shape[1]
+    W = tbl.shape[1] * Bs
+    qn, qr = _mla_q(p, x1, cfg, pos[:, None])
+    lat = _mla_latent(p, x1, cfg, pos[:, None])
+    wblk = tbl[jnp.arange(B), pos // Bs]
+    pool_kv = pool_kv.at[wblk, pos % Bs].set(lat[:, 0].astype(pool_kv.dtype))
+    q = _mla_absorb(p, qn[:, 0], qr[:, 0], cfg)             # [B,H,lat]
+    if impl == "pallas":
+        from repro.kernels.decode_attention.ops import decode_attention_mla
+        with jax.named_scope("attention"):
+            ol = decode_attention_mla(q, pool_kv, tbl, pos + 1,
+                                      scale=_mla_scale(cfg),
+                                      value_lanes=cfg.kv_lora_rank)
+    else:
+        with jax.named_scope("kv_window"):
+            ld = pool_kv[tbl].reshape(B, W, cfg.latent_lanes)
+        ol = _mla_decode_attend(q, ld, jnp.arange(W)[None, :] <= pos[:, None],
+                                cfg)
+    o = _mla_unabsorb(p, ol, cfg)
+    return _attn_out(p, o.astype(x1.dtype), cfg), pool_kv
+
+
 def decode_step_paged(params, cfg: ModelConfig, pool, tables, pos, tokens,
                       impl="xla"):
     """Batched decode step on the paged layout. tables: [L,B,nb]; pos,
-    tokens: [B]. Returns (logits [B,Vp], pool, pos+1). The layer scan
-    carries the pool, mirroring ``decode_step``'s cache carry — per layer
-    it scatters B rows and gathers B*W rows instead of touching the whole
-    dense [B,C] cache plane."""
+    tokens: [B]. Returns (logits [B,Vp], pool, out): ``out["pos"]`` is
+    pos+1, and a held-expert MoE model adds ``out["hits"]``, [MoE layers,
+    B, held experts] bool: which held experts each slot's token was routed
+    to. The layer scan carries the pool, mirroring ``decode_step``'s
+    cache carry — per layer it scatters B rows and gathers B*W rows
+    instead of touching the whole dense [B,C] cache plane."""
     x = embed_tokens(params, cfg, tokens[:, None])
 
-    def body(carry, inp):
-        x1, pk, pv = carry
+    def body(lcfg, carry, inp):
+        x1, pool = carry
         layer_p, tbl = inp
-        xn = L.rms_norm(x1, layer_p["attn_norm"], cfg.norm_eps)
-        attn_out, (pk, pv) = attn_decode_paged(layer_p, xn, cfg, pk, pv,
-                                               tbl, pos, impl=impl)
+        xn = L.rms_norm(x1, layer_p["attn_norm"], lcfg.norm_eps)
+        if lcfg.mla:
+            attn_out, kv = mla_decode_paged(layer_p, xn, lcfg, pool["kv"],
+                                            tbl, pos, impl=impl)
+            pool = {"kv": kv}
+        else:
+            attn_out, (pk, pv) = attn_decode_paged(
+                layer_p, xn, lcfg, pool["k"], pool["v"], tbl, pos, impl=impl)
+            pool = {"k": pk, "v": pv}
         x1 = x1 + attn_out
-        x1, _ = _ffn(layer_p, x1, cfg)
-        return (x1, pk, pv), None
+        x1, aux = _ffn(layer_p, x1, lcfg)
+        return (x1, pool), aux.get("held_hits")
 
-    (x, pk, pv), _ = jax.lax.scan(body, (x, pool["k"], pool["v"]),
-                                  (params["blocks"], tables))
+    (x, pool), hits = scan_layers(body, (x, pool), params, cfg, tables)
+    hits = [h for h in hits if h is not None]
     with jax.named_scope("head"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -839,7 +1102,10 @@ def decode_step_paged(params, cfg: ModelConfig, pool, tables, pos, tokens,
                             preferred_element_type=f32)
         logits = _mask_padded_vocab(logits, cfg)
         logits = constrain(logits, "dp", "vocab")
-    return logits, {"k": pk, "v": pv}, pos + 1
+    out = {"pos": pos + 1}
+    if hits:
+        out["hits"] = hits[0]
+    return logits, pool, out
 
 
 def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
@@ -854,12 +1120,14 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
     on the gathered (contiguous) context with ``q_offset`` — the
     chunked-prefill wiring for ``kernels/flash_attention``; ``"xla"``
     uses the same mask-based core as the dense path (bit-equal logits).
+    Latent attention writes each chunk's latent rows, expands the
+    gathered window through kv_b, and attends in XLA.
     """
     assert cfg.block == "attn" and not cfg.kv_quant
     emb, _ = embed_inputs(params, cfg, inputs)
     B, S, _ = emb.shape
     assert B == 1, "paged prefill is per-request (B=1)"
-    Bs = pool["k"].shape[1]
+    Bs = next(iter(pool.values())).shape[1]
     Hkvp, dh = cfg.padded_kv_heads, cfg.dh
     nb = tables.shape[1]
     W = nb * Bs
@@ -868,15 +1136,32 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
     assert S <= W, f"prompt {S} exceeds table window {W}"
     cb = chunk_size // Bs
 
-    def chunk_layers(x, pk, pv, lo):
+    def chunk_layers(x, pool, lo):
         # lo is a python int: block offsets below are static slices
-        def body(carry, inp):
-            xc, pk, pv = carry
+        def body(lcfg, carry, inp):
+            xc, pool = carry
             layer_p, tbl = inp                            # tbl: [nb]
-            xn = L.rms_norm(xc, layer_p["attn_norm"], cfg.norm_eps)
+            xn = L.rms_norm(xc, layer_p["attn_norm"], lcfg.norm_eps)
             positions = lo + jnp.arange(chunk_size)[None, :]
-            q, k, v = _qkv(layer_p, xn, cfg, positions)
             wids = tbl[lo // Bs:lo // Bs + cb]
+            qpos = lo + jnp.arange(chunk_size)[:, None]
+            if lcfg.mla:
+                qn, qr = _mla_q(layer_p, xn, lcfg, positions)
+                lat = _mla_latent(layer_p, xn, lcfg, positions)
+                pkv = pool["kv"].at[wids].set(
+                    lat[0].reshape(cb, Bs, -1).astype(pool["kv"].dtype))
+                pool = {"kv": pkv}
+                with jax.named_scope("kv_window"):
+                    ld = pkv[tbl].reshape(1, W, lcfg.latent_lanes)
+                k, v = _mla_expand(layer_p, ld, lcfg)
+                o = _chunk_attend(jnp.concatenate([qn, qr], axis=-1), k, v,
+                                  jnp.arange(W)[None, :] <= qpos, lcfg,
+                                  scale=_mla_scale(lcfg))
+                xc = xc + _attn_out(layer_p, o.astype(xc.dtype), lcfg)
+                xc, _ = _ffn(layer_p, xc, lcfg)
+                return (xc, pool), None
+            pk, pv = pool["k"], pool["v"]
+            q, k, v = _qkv(layer_p, xn, lcfg, positions)
             pk = pk.at[wids].set(
                 k[0].reshape(cb, Bs, Hkvp * dh).astype(pk.dtype))
             pv = pv.at[wids].set(
@@ -886,38 +1171,35 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
                 vd = pv[tbl].reshape(1, W, Hkvp, dh)
             ctx = lo + chunk_size
             if impl == "pallas":
-                assert cfg.padded_heads == cfg.num_heads, \
+                assert lcfg.padded_heads == lcfg.num_heads, \
                     "pallas path: no padding"
                 from repro.kernels.flash_attention.ops import flash_attention
                 o = flash_attention(
                     q, kd[:, :ctx], vd[:, :ctx], causal=True, q_offset=lo,
                     block_q=chunk_size, block_kv=chunk_size)
             else:
-                hmap = _head_map(cfg)
+                hmap = _head_map(lcfg)
                 kr = L.expand_kv(kd, hmap)
                 vr = L.expand_kv(vd, hmap)
-                qpos = lo + jnp.arange(chunk_size)[:, None]
                 mask = jnp.arange(W)[None, :] <= qpos
-                o = _chunk_attend(q, kr, vr, mask, cfg)
-            xc = xc + _attn_out(layer_p, o.astype(xc.dtype), cfg)
-            xc, _ = _ffn(layer_p, xc, cfg)
-            return (xc, pk, pv), None
+                o = _chunk_attend(q, kr, vr, mask, lcfg)
+            xc = xc + _attn_out(layer_p, o.astype(xc.dtype), lcfg)
+            xc, _ = _ffn(layer_p, xc, lcfg)
+            return (xc, {"k": pk, "v": pv}), None
 
-        (x, pk, pv), _ = jax.lax.scan(body, (x, pk, pv),
-                                      (params["blocks"], tables))
-        return x, pk, pv
+        (x, pool), _ = scan_layers(body, (x, pool), params, cfg, tables)
+        return x, pool
 
-    pk, pv = pool["k"], pool["v"]
     x_last = None
     for lo in range(start, S, chunk_size):
-        x_last, pk, pv = chunk_layers(emb[:, lo:lo + chunk_size], pk, pv, lo)
+        x_last, pool = chunk_layers(emb[:, lo:lo + chunk_size], pool, lo)
     with jax.named_scope("head"):
         x = L.rms_norm(x_last, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = jnp.einsum("bd,dv->bv", x[:, -1], head,
                             preferred_element_type=f32)
         logits = _mask_padded_vocab(logits, cfg)
-    return logits, {"k": pk, "v": pv}
+    return logits, pool
 
 
 def verify_chunk(params, cfg: ModelConfig, cache, tokens, start):
@@ -928,6 +1210,7 @@ def verify_chunk(params, cfg: ModelConfig, cache, tokens, start):
     cache). attn-family only.
     """
     assert cfg.block == "attn" and not cfg.kv_quant
+    assert not cfg.mla and not cfg.dense_layers
     emb, _ = embed_inputs(params, cfg, {"tokens": tokens})
     kv = (cache["k"], cache["v"])
     off = jnp.asarray(start, jnp.int32)

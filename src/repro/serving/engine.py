@@ -8,14 +8,15 @@ operation rather than a redeploy.
 Decode uses continuous batching over fixed slots. Two KV layouts share the
 same public surface:
 
-- **paged** (default for the dense-attention family): KV lives in a block
-  pool ``[num_blocks, block_size, Hkvp*dh]`` shared by all layers and
-  slots, addressed through per-layer block tables (``serving/blocks.py``
-  owns the host-side refcounts). ``insert`` scatters only the request's
-  blocks, ``evict`` is an O(1) refcount decrement per block, decode
-  attends over a pow2-bucketed window that tracks the *active* context
-  instead of the full per-slot capacity, and the prefix cache shares
-  blocks between entries copy-free.
+- **paged** (default for the attention family): KV lives in a block
+  pool ``[num_blocks, block_size, Hkvp*dh]`` (latent attention: one
+  ``[num_blocks, block_size, latent_lanes]`` pool of latent rows) shared
+  by all layers and slots, addressed through per-layer block tables
+  (``serving/blocks.py`` owns the host-side refcounts). ``insert``
+  scatters only the request's blocks, ``evict`` is an O(1) refcount
+  decrement per block, decode attends over a pow2-bucketed window that
+  tracks the *active* context instead of the full per-slot capacity, and
+  the prefix cache shares blocks between entries copy-free.
 - **dense** (fallback for rwkv/hybrid/sliding-window/kv-quant, or
   ``paged=False``): one ``[B_slots, capacity]``-wide cache with per-slot
   positions, as before.
@@ -75,13 +76,19 @@ class PagedCache:
     __slots__ = ("blocks", "length")
 
     def __init__(self, blocks: Dict[str, Any], length: int):
-        self.blocks = blocks            # {"k","v": [L, nb, Bs, Hkvp*dh]}
+        # {"k","v": [L, nb, Bs, Hkvp*dh]}, or {"kv": [L, nb, Bs, lanes]}
+        self.blocks = blocks
         self.length = int(length)
 
     @property
+    def num_blocks(self) -> int:
+        """Blocks per layer."""
+        return next(iter(self.blocks.values())).shape[1]
+
+    @property
     def nbytes(self) -> int:
-        bk = self.blocks["k"]
-        return 2 * int(np.prod(bk.shape)) * bk.dtype.itemsize
+        return sum(int(np.prod(b.shape)) * b.dtype.itemsize
+                   for b in self.blocks.values())
 
 
 class PrefixBlocks:
@@ -111,11 +118,15 @@ def _grow_cache(cache, capacity: int):
 
 
 def decode_impl(cfg: ModelConfig) -> str:
-    """Attention of the paged decode step: the Pallas kernel on a TPU for
-    the configs it takes (no padded heads, head size a multiple of the
-    128 lanes), else XLA, which is also the reference on the CPU."""
-    if (jax.default_backend() == "tpu"
-            and cfg.padded_heads == cfg.num_heads and cfg.dh % 128 == 0):
+    """Attention of the paged decode step: a Pallas kernel on a TPU for
+    the configs it takes, else XLA, which is also the reference on the
+    CPU. The GQA kernel takes no padded heads and a head size of whole
+    128-lane tiles; the latent kernel, values (c_kv) of whole tiles."""
+    if jax.default_backend() != "tpu":
+        return "xla"
+    if cfg.mla:
+        return "pallas" if cfg.kv_lora_rank % 128 == 0 else "xla"
+    if cfg.padded_heads == cfg.num_heads and cfg.dh % 128 == 0:
         return "pallas"
     return "xla"
 
@@ -285,16 +296,17 @@ class Engine:
 
     def _prefill_payload_impl(self, p, inputs):
         """Full prefill -> (logits, handoff blocks). The cache is reshaped
-        to [L, nb, Bs, Hkvp*dh] block tensors (block-padded true length —
-        never the slot capacity); logits are computed before any padding,
-        so they match the dense engine's bit-for-bit."""
+        to [L, nb, Bs, lanes] block tensors, one per pool array
+        (block-padded true length — never the slot capacity); logits are
+        computed before any padding, so they match the dense engine's
+        bit-for-bit."""
         logits, cache = T.prefill_full(p, self.cfg, inputs)
         S = inputs["tokens"].shape[1]
         Bs = self.block_size
         Sb = -(-S // Bs) * Bs
         blocks = {}
-        for kk in ("k", "v"):
-            row = cache[kk][:, 0]                         # [L, S, Hkvp, dh]
+        for kk in self.pool:
+            row = cache[kk][:, 0]              # [L, S, Hkvp, dh] or lanes
             if Sb > S:
                 pad = jnp.zeros((row.shape[0], Sb - S) + row.shape[2:],
                                 row.dtype)
@@ -515,7 +527,7 @@ class Engine:
         if not isinstance(payload, PagedCache):
             raise TypeError("paged engine got a dense handoff payload; "
                             "mixed-layout fleets are unsupported")
-        nbk = payload.blocks["k"].shape[1]
+        nbk = payload.num_blocks
         Lr = self.cfg.num_layers
         with span("serve.insert.blocks"):
             try:
@@ -582,7 +594,7 @@ class Engine:
                 nb *= 2
             nb = min(nb, self._nb_max)
         with span("serve.decode",
-                  **self._decode_counters(tokens_by_slot, nb)):
+                  **self._decode_counters(tokens_by_slot, nb)) as sp:
             # grow: a slot whose next write crosses a block boundary gets a
             # fresh block per layer *before* the jit'd step (O(1) host work)
             with span("serve.decode.blocks"):
@@ -600,12 +612,16 @@ class Engine:
                 pos = jnp.asarray(self._pos)
                 toks = jnp.asarray(toks)
             with span("serve.decode.dispatch"):
-                logits, self.pool, _ = self._decode_paged(
+                logits, self.pool, out = self._decode_paged(
                     self.params, self.pool, tables, pos, toks)
             with span("serve.decode.sync"):
-                nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size],
-                                            axis=-1))
-                jax.block_until_ready(nxt)
+                # the routing hits come back in the tokens' transfer
+                nxt, hits = jax.device_get(
+                    (jnp.argmax(logits[:, :self.cfg.vocab_size], axis=-1),
+                     out.get("hits")))
+            if sp is not None and hits is not None:
+                sp.set_metadata(**self._expert_counters(hits,
+                                                        tokens_by_slot))
             for s in tokens_by_slot:
                 self._pos[s] += 1
             self._tick(t0)
@@ -622,6 +638,19 @@ class Engine:
         live = sum(int(self._pos[s]) + 1 for s in tokens_by_slot)
         return {"window_blocks": nb, "slots": self.slots,
                 "live_tokens": live}
+
+    @staticmethod
+    def _expert_counters(hits: np.ndarray,
+                         tokens_by_slot: Dict[int, int]) -> Dict[str, int]:
+        """The ``serve.decode`` span's counters of a held-expert MoE model,
+        from the step's routing hits [MoE layers, slots, held experts] over
+        the active slots, summed over the MoE layers: tokens the held
+        experts computed, held experts with at least one token, and tokens
+        through the MoE layers (each layer's router and shared expert)."""
+        act = hits[:, sorted(tokens_by_slot)]
+        return {"expert_tokens": int(act.sum()),
+                "experts_touched": int(act.any(axis=1).sum()),
+                "moe_tokens": int(act.shape[0] * act.shape[1])}
 
     @property
     def mean_step_s(self) -> float:

@@ -59,6 +59,7 @@ def test_full_config_matches_assignment(arch):
         "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840),
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155),
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
+        "deepseek-v3": (61, 7168, 128, 128, 18432, 129280),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
@@ -76,3 +77,13 @@ def test_full_config_matches_assignment(arch):
         assert cfg.qk_norm
     if arch == "rwkv6-1.6b":
         assert cfg.block == "rwkv" and cfg.is_attention_free
+    if arch == "deepseek-v3":
+        assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_dim,
+                cfg.qk_rope_dim, cfg.v_head_dim) == (1536, 512, 128, 64, 128)
+        assert cfg.first_k_dense == 3 and cfg.yarn.factor == 40
+        assert (cfg.moe.num_experts, cfg.moe.top_k, cfg.moe.d_ff_expert,
+                cfg.moe.num_shared_experts) == (256, 8, 2048, 1)
+        assert (cfg.moe.router, cfg.moe.n_group, cfg.moe.topk_group,
+                cfg.moe.routed_scaling) == ("sigmoid", 8, 4, 2.5)
+        # MLA keeps 576 latent lanes a token per layer
+        assert cfg.kv_bytes_per_token() == 61 * 576 * 2

@@ -7,8 +7,10 @@ import pytest
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.decode_attention.ops import (decode_attention,
+                                                decode_attention_mla,
                                                 decode_attention_paged)
-from repro.kernels.decode_attention.ref import (decode_attention_paged_ref,
+from repro.kernels.decode_attention.ref import (decode_attention_mla_ref,
+                                                decode_attention_paged_ref,
                                                 decode_attention_ref)
 from repro.kernels.rwkv6.ops import wkv
 from repro.kernels.rwkv6.ref import wkv_ref
@@ -171,6 +173,74 @@ def test_decode_attention_paged_garbage_block_immunity():
         jnp.asarray(q), jnp.asarray(pool_k2), jnp.asarray(pool_v2),
         jnp.asarray(tables), jnp.asarray(lengths), interpret=True)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
+
+
+def _latent_case(rng, B, N, Bs, lanes, used, lengths, nb=None):
+    """A latent pool [N, Bs, lanes] whose lanes past ``used`` are zero (as
+    the program pads its rows), with per-sequence tables as _paged_case."""
+    pool = np.zeros((N, Bs, lanes), np.float32)
+    pool[..., :used] = rng.standard_normal((N, Bs, used))
+    _, _, tables = _paged_case(rng, len(lengths), N, Bs, 1, 1, lengths, nb)
+    return pool, tables
+
+
+# the latent kernel against its numpy twin; tolerances as the GQA kernel's:
+# f32 differs in summation order only, bf16 rounds each p at another scale
+@pytest.mark.parametrize("B,H,lanes,used,value,Bs,N,lengths", [
+    (2, 4, 128, 40, 32, 8, 32, (37, 16)),
+    (3, 16, 256, 192, 128, 16, 16, (64, 1, 90)),
+    (1, 8, 128, 96, 64, 8, 64, (200,)),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_mla_matches_its_twin(B, H, lanes, used, value, Bs,
+                                               N, lengths, dtype):
+    rng = np.random.default_rng(17)
+    pool, tables = _latent_case(rng, B, N, Bs, lanes, used, lengths)
+    q = np.zeros((B, H, lanes), np.float32)
+    q[..., :used] = rng.standard_normal((B, H, used))
+    lens = np.asarray(lengths, np.int32)
+    qj, pj = jnp.asarray(q, dtype), jnp.asarray(pool, dtype)
+    out = decode_attention_mla(qj, pj, jnp.asarray(tables), jnp.asarray(lens),
+                               scale=0.11, value_lanes=value, interpret=True)
+    ref = decode_attention_mla_ref(np.asarray(qj, np.float32),
+                                   np.asarray(pj, np.float32), tables, lens,
+                                   scale=0.11, value_lanes=value)
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                               atol=tol(dtype), rtol=tol(dtype))
+
+
+# DeepSeek-V3's published widths (128 heads, 640-lane rows of 576 used,
+# 512 value lanes, block size 8) against the absorbed XLA core the engine
+# runs off the TPU, over the gathered window; tolerances as above
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_latent_kernel_matches_xla_decode(dtype):
+    from repro.configs import get_config
+    from repro.kernels.decode_attention.paged_decode import chunk_blocks
+    from repro.models.transformer import _mla_decode_attend, _mla_scale
+    cfg = get_config("deepseek-v3")
+    H, lanes, Bs, nb = cfg.num_heads, cfg.latent_lanes, 8, 64
+    chunk = Bs * chunk_blocks(Bs, lanes, jnp.dtype(dtype).itemsize, nb)
+    # one key, one block, one past it, a chunk, one past a chunk, the
+    # whole table; the last slot is inactive: pos 0 on the trash block
+    lengths = (1, 8, 9, chunk, chunk + 1, nb * Bs)
+    B = len(lengths) + 1
+    rng = np.random.default_rng(6)
+    N = 1 + sum(-(-l // Bs) for l in lengths)
+    pool, tables = _latent_case(rng, B, N, Bs, lanes, cfg.latent_dim,
+                                lengths + (0,), nb)
+    lens = np.asarray(lengths + (1,), np.int32)
+    q = np.zeros((B, H, lanes), np.float32)
+    q[..., :cfg.latent_dim] = rng.standard_normal((B, H, cfg.latent_dim))
+    qj, pj = jnp.asarray(q, dtype), jnp.asarray(pool, dtype)
+    out = decode_attention_mla(qj, pj, jnp.asarray(tables), jnp.asarray(lens),
+                               scale=_mla_scale(cfg),
+                               value_lanes=cfg.kv_lora_rank, interpret=True)
+    W = nb * Bs
+    valid = jnp.arange(W)[None, :] < lens[:, None]
+    ref = _mla_decode_attend(qj, pj[tables].reshape(B, W, lanes), valid, cfg)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=tol(dtype), rtol=tol(dtype))
 
 
 @pytest.mark.parametrize("B,S,H,N,chunk", [

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.config import ModelConfig, MoEConfig
+from repro.models.config import ModelConfig, MoEConfig, YarnConfig
 from repro.models import transformer as T
 from repro.models import layers as L
 
@@ -23,6 +23,16 @@ FAMILIES = {
     "rwkv": ModelConfig(name="rwkv", family="ssm", block="rwkv", **TINY),
     "hybrid": ModelConfig(name="hy", family="hybrid", block="hybrid",
                           sliding_window=8, ssm_state=4, **TINY),
+    # latent attention (expanded in prefill, absorbed in decode), YaRN,
+    # a dense layer then a held-expert MoE layer with the grouped router
+    "mla+moe": ModelConfig(
+        name="mla", family="moe", q_lora_rank=16, kv_lora_rank=32,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, first_k_dense=1,
+        yarn=YarnConfig(factor=40.0, original_max_position=4096),
+        moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32,
+                      num_shared_experts=1, router="sigmoid", n_group=4,
+                      topk_group=2, routed_scaling=2.5, held=(2, 4)),
+        **TINY),
 }
 
 KEY = jax.random.PRNGKey(1)

@@ -292,3 +292,24 @@ def test_engine_chooses_the_paged_decode_kernel(monkeypatch, backend,
                              eng.pool, tbl, start=0)
     dense = Engine(1, cfg, params, slots=2, capacity=32, paged=False)
     assert dense.decode_impl == "xla"
+
+
+def test_engine_chooses_the_latent_decode_kernel(monkeypatch):
+    """A latent-attention engine attends in its decode step through the
+    latent paged kernel on a TPU (c_kv of whole 128-lane tiles) and in
+    XLA elsewhere; its pool is one array of latent rows."""
+    import dataclasses
+    cfg = dataclasses.replace(KERNEL_CFG, num_kv_heads=2, q_lora_rank=32,
+                              kv_lora_rank=128, qk_nope_dim=32,
+                              qk_rope_dim=64, v_head_dim=32)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    z = np.zeros((2,), np.int32)
+    for backend, impl in (("cpu", "xla"), ("tpu", "pallas")):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        eng = Engine(0, cfg, params, slots=2, capacity=32, chunk_size=8)
+        assert eng.decode_impl == impl
+        assert set(eng.pool) == {"kv"}
+        assert eng.pool["kv"].shape[-1] == 256       # 192 lanes used
+        assert _takes_kernel(eng._decode_paged, params, eng.pool,
+                             eng._tables[:, :, :2], z, z) == (
+                                 impl == "pallas")

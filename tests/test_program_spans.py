@@ -12,6 +12,7 @@
 - Unbound or with no profiler session, ``span`` is one shared no-op, and
   a fleet of simulated engines never imports jax.
 """
+import dataclasses
 import os
 import re
 import subprocess
@@ -272,3 +273,60 @@ def test_simulated_fleet_never_imports_jax():
         print(sorted(m for m in sys.modules if m.split(".")[0] == "jax"))
     """)
     assert _run(code).strip() == "[]"
+
+
+DSV3 = get_smoke_config("deepseek-v3")
+# the benchmark's cut at smoke size: 8 of 32 experts held, from expert 8
+DSV3 = DSV3.replace(moe=dataclasses.replace(DSV3.moe, held=(8, 8)))
+
+
+@pytest.fixture(scope="module")
+def dsv3_engine():
+    params = T.init_params(DSV3, jax.random.PRNGKey(1))
+    return Engine(0, DSV3, params, slots=4, capacity=64, chunk_size=CHUNK)
+
+
+def test_latent_moe_decode_step_is_scoped(dsv3_engine):
+    """The latent model's decode step carries the scopes its metrics read:
+    ``attention`` (the latent core), ``moe`` (router, held and shared
+    experts, combine) inside ``mlp``, ``kv_window`` off the TPU."""
+    eng = dsv3_engine
+    slots = jnp.zeros((eng.slots,), jnp.int32)
+    tables = jnp.zeros((DSV3.num_layers, eng.slots, 2), jnp.int32)
+    lowered = eng._decode_paged.lower(eng.params, eng.pool, tables, slots,
+                                      slots)
+    paths = re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True))
+    scopes = {part for path in paths for part in path.split("/")[:-1]}
+    assert {"kv_window", "attention", "mlp", "moe", "head"} <= scopes
+
+
+def test_latent_moe_decode_span_carries_expert_counters(dsv3_engine,
+                                                        tmp_path):
+    """``serve.decode`` of a held-expert model adds, from the routing hits
+    the step returns: held-expert passes, held experts touched, and
+    tokens through the MoE layers, each summed over the MoE layers."""
+    cl = Cluster({"mixed": [dsv3_engine]},
+                 scheduler=ChunkedPiggybackScheduler(CHUNK),
+                 router=KVLocalityRouter(), sanitize=False)
+    cl.serve(StaticWorkload(_requests(4, 2)))         # compile first
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        cl.serve(StaticWorkload(_requests(4, 2)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    dec = [dict(e.stats) for plane in
+           jax.profiler.ProfileData.from_file(str(path)).planes
+           for ln in plane.lines for e in ln.events
+           if e.name == "serve.decode"]
+    assert dec
+    moe_layers = DSV3.num_layers - DSV3.first_k_dense
+    held = DSV3.moe.held[1]
+    for c in dec:
+        assert {"expert_tokens", "experts_touched", "moe_tokens"} <= set(c)
+        batch = c["moe_tokens"] // moe_layers
+        assert 1 <= batch <= 4 and c["moe_tokens"] == batch * moe_layers
+        assert c["experts_touched"] <= c["expert_tokens"]
+        assert c["expert_tokens"] <= c["moe_tokens"] * DSV3.moe.top_k
+        assert c["experts_touched"] <= moe_layers * held
+    assert sum(c["expert_tokens"] for c in dec) > 0

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core.hardware import TPU_V5E
 from repro.kernels.decode_attention.ops import (decode_attention,
+                                                decode_attention_mla,
                                                 decode_attention_paged)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.rwkv6.ops import wkv
@@ -30,6 +31,11 @@ BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 QWEN = get_config("qwen2.5-3b")
 RWKV = get_config("rwkv6-1.6b")
 H, HKV, DH = QWEN.num_heads, QWEN.num_kv_heads, QWEN.dh
+# the benchmark's DeepSeek-V3 share: 7 layers (3 dense, 4 MoE), experts
+# 0-7 of 256 held, an eighth of the vocabulary
+_DSV3 = get_config("deepseek-v3")
+DSV3 = dataclasses.replace(_DSV3, num_layers=7, vocab_size=16160,
+                           moe=dataclasses.replace(_DSV3.moe, held=(0, 8)))
 
 
 @pytest.fixture(scope="module")
@@ -93,6 +99,13 @@ KERNELS = {
         decode_attention_paged,
         [((8, H, DH), BF), ((1025, 16, HKV * DH), BF),
          ((1025, 16, HKV * DH), BF), ((8, 128), I32), ((8,), I32)]),
+    # DeepSeek-V3's latent rows: 128 heads, 640 lanes (576 used), 512
+    # value lanes, block size 8
+    "paged_latent_decode": (
+        lambda q, pool, tbl, lens: decode_attention_mla(
+            q, pool, tbl, lens, scale=0.1, value_lanes=DSV3.kv_lora_rank),
+        [((8, DSV3.num_heads, DSV3.latent_lanes), BF),
+         ((2049, 8, DSV3.latent_lanes), BF), ((8, 256), I32), ((8,), I32)]),
     "wkv": (
         wkv,
         [((1, 256, RWKV.num_heads, RWKV.dh), F32)] * 4
@@ -178,4 +191,48 @@ def test_full_width_prefill_full_fits_v5e(one_chip, qwen_params):
     (tokens,) = _shapes(one_chip, ((1, 2048), I32))
     step = jax.jit(lambda p, t: T.prefill_full(p, QWEN, {"tokens": t}))
     compiled = step.lower(qwen_params, tokens).compile()
+    assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
+
+
+@pytest.fixture(scope="module")
+def dsv3_params(one_chip):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        T.abstract_params(DSV3))
+
+
+def _dsv3_pool(one_chip, slots=128, capacity=2560, bs=8):
+    """The latent pool the engine sizes for the cell's 128 slots."""
+    nb = capacity // bs
+    blocks = 1 + DSV3.num_layers * nb * (slots + 4)
+    (kv,) = _shapes(one_chip, ((blocks, bs, DSV3.latent_lanes), BF))
+    return {"kv": kv}, nb, blocks * bs * DSV3.latent_lanes * 2
+
+
+def test_deepseek_v3_decode_step_on_kernel_fits_v5e(one_chip, dsv3_params):
+    """The DeepSeek-V3 share's decode step at 128 slots on the latent
+    kernel: it fits HBM beside the weights and the pool, and reads the
+    pool in place (temporaries under a quarter of it)."""
+    slots = 128
+    pool, nb, pool_bytes = _dsv3_pool(one_chip, slots)
+    tables, pos, tokens = _shapes(
+        one_chip, ((DSV3.num_layers, slots, nb), I32), ((slots,), I32),
+        ((slots,), I32))
+    step = jax.jit(lambda p, pool, tbl, pos, t: T.decode_step_paged(
+        p, DSV3, pool, tbl, pos, t, impl="pallas"), donate_argnums=(1,))
+    compiled = step.lower(dsv3_params, pool, tables, pos, tokens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+def test_deepseek_v3_chunked_prefill_fits_v5e(one_chip, dsv3_params):
+    """The share's chunk-128 prefill of the cell's longest prompt (1024
+    tokens, 8 chunks) into the same pool."""
+    pool, _, _ = _dsv3_pool(one_chip)
+    tokens, tbl = _shapes(one_chip, ((1, 1024), I32),
+                          ((DSV3.num_layers, 1024 // 8), I32))
+    step = jax.jit(lambda p, t, pool, tbl: T.prefill_chunked_paged(
+        p, DSV3, {"tokens": t}, 128, pool, tbl), donate_argnums=(2,))
+    compiled = step.lower(dsv3_params, tokens, pool, tbl).compile()
     assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
