@@ -181,11 +181,10 @@ def phase_kernels(seed: int):
     tables = np.zeros((B, nb), np.int32)
     for b, n in enumerate(nbs):
         tables[b, :n] = [next(ids) for _ in range(n)]
-    pk, pv = bf(randn(N, Bs, Hkv * dh)), bf(randn(N, Bs, Hkv * dh))
-    out = decode_attention_paged(q, pk, pv, jnp.asarray(tables),
+    pool = bf(randn(N, Bs, 2 * Hkv * dh))          # a row: K heads, V heads
+    out = decode_attention_paged(q, pool, jnp.asarray(tables),
                                  jnp.asarray(lengths))
-    ref = decode_attention_paged_ref(f32(q), f32(pk), f32(pv), tables,
-                                     lengths)
+    ref = decode_attention_paged_ref(f32(q), f32(pool), tables, lengths)
     lines.append(close("paged_decode", out, ref))
 
     # latent paged decode (MLA, absorbed) over the same tables: one

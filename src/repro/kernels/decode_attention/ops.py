@@ -45,23 +45,24 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def decode_attention_paged(q, pool_k, pool_v, tables, lengths, *,
+def decode_attention_paged(q, pool, tables, lengths, *,
                            interpret: bool = False):
-    """Paged-layout decode attention. q: [B, H, dh]; pools:
-    [N, Bs, Hkv*dh] (kv heads folded into lanes); tables: [B, nb] int32
-    block ids in sequence order; lengths: [B] keys to attend (>= 1).
+    """Paged-layout decode attention. q: [B, H, dh]; pool:
+    [N, Bs, 2*Hkv*dh], each row a token's K heads then its V heads;
+    tables: [B, nb] int32 block ids in sequence order; lengths: [B] keys
+    to attend (>= 1).
 
     Returns [B, H, dh]. The kernel reads each slot's live blocks in place
     and normalizes its own output (see paged_decode.py): no merge here.
     """
     B, H, dh = q.shape
-    Hkv = pool_k.shape[2] // dh
+    Hkv = pool.shape[2] // (2 * dh)
     assert H % Hkv == 0
     G = H // Hkv
     scale = 1.0 / math.sqrt(dh)
     o = paged_decode_attention_kernel(
-        q.reshape(B, Hkv, G, dh), pool_k, pool_v, tables, lengths,
-        scale=scale, interpret=interpret)
+        q.reshape(B, Hkv, G, dh), pool, tables, lengths, scale=scale,
+        interpret=interpret)
     return o.reshape(B, H, dh)
 
 

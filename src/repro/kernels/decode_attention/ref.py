@@ -23,29 +23,30 @@ def decode_attention_ref(q, k_cache, v_cache, lengths, *, scale=None):
     return jnp.einsum("bhk,bkhd->bhd", p, v).astype(q.dtype)
 
 
-def decode_attention_paged_ref(q, pool_k, pool_v, tables, lengths, *,
-                               scale=None):
-    """Pure-numpy paged oracle. q: [B,H,dh]; pools: [N,Bs,Hkv*dh];
-    tables: [B,nb]; lengths: [B]. -> [B,H,dh] (f32 math)."""
+def decode_attention_paged_ref(q, pool, tables, lengths, *, scale=None):
+    """Pure-numpy paged oracle. q: [B,H,dh]; pool: [N,Bs,2*Hkv*dh], each
+    row a token's K heads then its V heads; tables: [B,nb]; lengths: [B].
+    -> [B,H,dh] (f32 math)."""
     q = np.asarray(q, np.float32)
-    pool_k = np.asarray(pool_k, np.float32)
-    pool_v = np.asarray(pool_v, np.float32)
+    pool = np.asarray(pool, np.float32)
     tables = np.asarray(tables)
     lengths = np.asarray(lengths)
     B, H, dh = q.shape
-    _, Bs, lanes = pool_k.shape
-    Hkv = lanes // dh
+    _, Bs, lanes = pool.shape
+    Hkv = lanes // (2 * dh)
     nb = tables.shape[1]
     W = nb * Bs
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    k = pool_k[tables].reshape(B, W, Hkv, dh)     # gather through the table
-    v = pool_v[tables].reshape(B, W, Hkv, dh)
-    k = np.repeat(k, H // Hkv, axis=2)
-    v = np.repeat(v, H // Hkv, axis=2)
+    # gather through the table, then split each row into K and V
+    rows = pool[tables].reshape(B, W, 2, Hkv, dh)
+    k = np.repeat(rows[:, :, 0], H // Hkv, axis=2)
+    v = np.repeat(rows[:, :, 1], H // Hkv, axis=2)
     s = np.einsum("bhd,bkhd->bhk", q, k) * scale
     mask = np.arange(W)[None, None, :] < lengths[:, None, None]
     s = np.where(mask, s, -np.inf)
-    s = s - s.max(axis=-1, keepdims=True)
+    # a slot of length 0 attends nothing: its output is 0
+    m = s.max(axis=-1, keepdims=True)
+    s = s - np.where(np.isfinite(m), m, 0.0)
     e = np.where(mask, np.exp(s), 0.0)
     p = e / np.maximum(e.sum(axis=-1, keepdims=True), 1e-30)
     return np.einsum("bhk,bkhd->bhd", p, v)

@@ -948,8 +948,9 @@ def prefill_chunked(params, cfg: ModelConfig, inputs, chunk_size: int,
 # Paged KV cache (block pool + per-layer block tables)
 #
 # Layout: one pool of KV blocks shared by every layer and request,
-#   pool = {"k", "v": [num_blocks, block_size, Hkvp*dh]}
-# (latent attention: pool = {"kv": [num_blocks, block_size, latent_lanes]})
+#   pool = {"kv": [num_blocks, block_size, 2*Hkvp*dh]}
+# a token's row holding its K heads, then its V heads (latent attention:
+# {"kv": [num_blocks, block_size, latent_lanes]}, one latent row a token)
 # with per-request *per-layer* block tables [L, nb] (int32 block ids).
 # Host-side ownership/refcounts live in serving/blocks.py; everything here
 # is the pure compute: decode gathers K/V through the table, prefill
@@ -969,17 +970,25 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 
 def init_block_pool(cfg: ModelConfig, num_blocks: int, block_size: int):
-    """Zero-filled block pool: {"k","v": [N, Bs, Hkvp*dh]} (a token's kv
-    heads lie side by side in lanes), or for latent attention one
-    {"kv": [N, Bs, latent_lanes]} (a token's latent row). Either way one
-    block is Bs lane-dense rows, the layout the paged decode kernels read
-    in place."""
-    if cfg.mla:
-        return {"kv": jnp.zeros((num_blocks, block_size, cfg.latent_lanes),
-                                cfg.jdtype)}
-    shape = (num_blocks, block_size, cfg.padded_kv_heads * cfg.dh)
-    return {"k": jnp.zeros(shape, cfg.jdtype),
-            "v": jnp.zeros(shape, cfg.jdtype)}
+    """Zero-filled block pool, one array {"kv": [N, Bs, lanes]}: a
+    token's row is its K heads side by side in the first Hkvp*dh lanes
+    and its V heads in the next Hkvp*dh (``kv_rows``), or for latent
+    attention its latent row. Either way one block is Bs lane-dense rows,
+    the layout the paged decode kernels read in place with one copy."""
+    lanes = cfg.latent_lanes if cfg.mla else 2 * cfg.padded_kv_heads * cfg.dh
+    return {"kv": jnp.zeros((num_blocks, block_size, lanes), cfg.jdtype)}
+
+
+def kv_rows(k, v):
+    """K and V [..., Hkvp, dh] -> the pool's rows [..., 2*Hkvp*dh]."""
+    return jnp.concatenate([k.reshape(k.shape[:-2] + (-1,)),
+                            v.reshape(v.shape[:-2] + (-1,))], axis=-1)
+
+
+def split_kv_rows(rows, cfg: ModelConfig):
+    """The pool's rows [..., 2*Hkvp*dh] -> K and V [..., Hkvp, dh]."""
+    kv = rows.reshape(rows.shape[:-1] + (2, cfg.padded_kv_heads, cfg.dh))
+    return kv[..., 0, :, :], kv[..., 1, :, :]
 
 
 def gather_blocks(pool, ids):
@@ -998,42 +1007,37 @@ def scatter_blocks(pool, ids, blocks):
             for k, a in pool.items()}
 
 
-def attn_decode_paged(p, x1, cfg: ModelConfig, pool_k, pool_v, tbl, pos,
+def attn_decode_paged(p, x1, cfg: ModelConfig, pool_kv, tbl, pos,
                       impl="xla"):
     """One decode token against the paged layout. x1: [B,1,D] (normed);
-    pool_k/v: [N,Bs,Hkvp*dh]; tbl: [B,nb]; pos: [B]. Writes this token's
-    K/V into the slot's current block, then attends over the slot's first
-    pos+1 keys. Inactive slots must point at the trash block with pos=0
-    (their write lands there; nothing reads it).
+    pool_kv: [N,Bs,2*Hkvp*dh]; tbl: [B,nb]; pos: [B]. Writes this token's
+    K/V row into the slot's current block, then attends over the slot's
+    first pos+1 keys. Inactive slots must point at the trash block with
+    pos=0 (their write lands there; nothing reads it).
 
     ``impl="pallas"`` attends through ``kernels/decode_attention``'s paged
     kernel, which reads each slot's live blocks where they lie in the
     pool; ``"xla"`` gathers the pow2 window W = nb*Bs of every slot and
     runs the dense decode core (bit-equal logits with the dense cache)."""
     B = x1.shape[0]
-    Bs = pool_k.shape[1]
+    Bs = pool_kv.shape[1]
     W = tbl.shape[1] * Bs
     q, k, v = _qkv(p, x1, cfg, pos[:, None])
-    bidx = jnp.arange(B)
-    wblk = tbl[bidx, pos // Bs]                               # [B]
-    off = pos % Bs
-    pool_k = pool_k.at[wblk, off].set(k[:, 0].reshape(B, -1)
-                                      .astype(pool_k.dtype))
-    pool_v = pool_v.at[wblk, off].set(v[:, 0].reshape(B, -1)
-                                      .astype(pool_v.dtype))
+    wblk = tbl[jnp.arange(B), pos // Bs]                      # [B]
+    pool_kv = pool_kv.at[wblk, pos % Bs].set(
+        kv_rows(k[:, 0], v[:, 0]).astype(pool_kv.dtype))
     if impl == "pallas":
         assert cfg.padded_heads == cfg.num_heads, "pallas path: no padding"
         from repro.kernels.decode_attention.ops import decode_attention_paged
         with jax.named_scope("attention"):
-            o = decode_attention_paged(q[:, 0], pool_k, pool_v, tbl, pos + 1)
+            o = decode_attention_paged(q[:, 0], pool_kv, tbl, pos + 1)
         o = o[:, None]                                        # [B,1,H,dh]
     else:
         with jax.named_scope("kv_window"):
-            kd = pool_k[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
-            vd = pool_v[tbl].reshape(B, W, cfg.padded_kv_heads, cfg.dh)
+            kd, vd = split_kv_rows(pool_kv[tbl].reshape(B, W, -1), cfg)
         valid = jnp.arange(W)[None, :] <= pos[:, None]
         o = _decode_attend(p, q, kd, vd, valid, cfg)
-    return _attn_out(p, o.astype(x1.dtype), cfg), (pool_k, pool_v)
+    return _attn_out(p, o.astype(x1.dtype), cfg), pool_kv
 
 
 def mla_decode_paged(p, x1, cfg: ModelConfig, pool_kv, tbl, pos,
@@ -1081,14 +1085,10 @@ def decode_step_paged(params, cfg: ModelConfig, pool, tables, pos, tokens,
         x1, pool = carry
         layer_p, tbl = inp
         xn = L.rms_norm(x1, layer_p["attn_norm"], lcfg.norm_eps)
-        if lcfg.mla:
-            attn_out, kv = mla_decode_paged(layer_p, xn, lcfg, pool["kv"],
-                                            tbl, pos, impl=impl)
-            pool = {"kv": kv}
-        else:
-            attn_out, (pk, pv) = attn_decode_paged(
-                layer_p, xn, lcfg, pool["k"], pool["v"], tbl, pos, impl=impl)
-            pool = {"k": pk, "v": pv}
+        attend = mla_decode_paged if lcfg.mla else attn_decode_paged
+        attn_out, kv = attend(layer_p, xn, lcfg, pool["kv"], tbl, pos,
+                              impl=impl)
+        pool = {"kv": kv}
         x1 = x1 + attn_out
         x1, aux = _ffn(layer_p, x1, lcfg)
         return (x1, pool), aux.get("held_hits")
@@ -1127,8 +1127,7 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
     emb, _ = embed_inputs(params, cfg, inputs)
     B, S, _ = emb.shape
     assert B == 1, "paged prefill is per-request (B=1)"
-    Bs = next(iter(pool.values())).shape[1]
-    Hkvp, dh = cfg.padded_kv_heads, cfg.dh
+    Bs = pool["kv"].shape[1]
     nb = tables.shape[1]
     W = nb * Bs
     assert chunk_size % Bs == 0, "chunks must be block-aligned"
@@ -1147,33 +1146,26 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
             qpos = lo + jnp.arange(chunk_size)[:, None]
             if lcfg.mla:
                 qn, qr = _mla_q(layer_p, xn, lcfg, positions)
-                lat = _mla_latent(layer_p, xn, lcfg, positions)
-                pkv = pool["kv"].at[wids].set(
-                    lat[0].reshape(cb, Bs, -1).astype(pool["kv"].dtype))
-                pool = {"kv": pkv}
-                with jax.named_scope("kv_window"):
-                    ld = pkv[tbl].reshape(1, W, lcfg.latent_lanes)
-                k, v = _mla_expand(layer_p, ld, lcfg)
+                rows = _mla_latent(layer_p, xn, lcfg, positions)[0]
+            else:
+                q, k, v = _qkv(layer_p, xn, lcfg, positions)
+                rows = kv_rows(k[0], v[0])
+            pkv = pool["kv"].at[wids].set(
+                rows.reshape(cb, Bs, -1).astype(pool["kv"].dtype))
+            with jax.named_scope("kv_window"):
+                window = pkv[tbl].reshape(1, W, -1)
+                if not lcfg.mla:
+                    kd, vd = split_kv_rows(window, lcfg)
+            if lcfg.mla:
+                k, v = _mla_expand(layer_p, window, lcfg)
                 o = _chunk_attend(jnp.concatenate([qn, qr], axis=-1), k, v,
                                   jnp.arange(W)[None, :] <= qpos, lcfg,
                                   scale=_mla_scale(lcfg))
-                xc = xc + _attn_out(layer_p, o.astype(xc.dtype), lcfg)
-                xc, _ = _ffn(layer_p, xc, lcfg)
-                return (xc, pool), None
-            pk, pv = pool["k"], pool["v"]
-            q, k, v = _qkv(layer_p, xn, lcfg, positions)
-            pk = pk.at[wids].set(
-                k[0].reshape(cb, Bs, Hkvp * dh).astype(pk.dtype))
-            pv = pv.at[wids].set(
-                v[0].reshape(cb, Bs, Hkvp * dh).astype(pv.dtype))
-            with jax.named_scope("kv_window"):
-                kd = pk[tbl].reshape(1, W, Hkvp, dh)
-                vd = pv[tbl].reshape(1, W, Hkvp, dh)
-            ctx = lo + chunk_size
-            if impl == "pallas":
+            elif impl == "pallas":
                 assert lcfg.padded_heads == lcfg.num_heads, \
                     "pallas path: no padding"
                 from repro.kernels.flash_attention.ops import flash_attention
+                ctx = lo + chunk_size
                 o = flash_attention(
                     q, kd[:, :ctx], vd[:, :ctx], causal=True, q_offset=lo,
                     block_q=chunk_size, block_kv=chunk_size)
@@ -1185,7 +1177,7 @@ def prefill_chunked_paged(params, cfg: ModelConfig, inputs, chunk_size: int,
                 o = _chunk_attend(q, kr, vr, mask, lcfg)
             xc = xc + _attn_out(layer_p, o.astype(xc.dtype), lcfg)
             xc, _ = _ffn(layer_p, xc, lcfg)
-            return (xc, {"k": pk, "v": pv}), None
+            return (xc, {"kv": pkv}), None
 
         (x, pool), _ = scan_layers(body, (x, pool), params, cfg, tables)
         return x, pool

@@ -8,9 +8,10 @@ operation rather than a redeploy.
 Decode uses continuous batching over fixed slots. Two KV layouts share the
 same public surface:
 
-- **paged** (default for the attention family): KV lives in a block
-  pool ``[num_blocks, block_size, Hkvp*dh]`` (latent attention: one
-  ``[num_blocks, block_size, latent_lanes]`` pool of latent rows) shared
+- **paged** (default for the attention family): KV lives in one block
+  pool ``[num_blocks, block_size, 2*Hkvp*dh]``, a token's K heads and
+  then its V heads in one row (latent attention: ``[num_blocks,
+  block_size, latent_lanes]``, one latent row a token), shared
   by all layers and slots, addressed through per-layer block tables
   (``serving/blocks.py`` owns the host-side refcounts). ``insert``
   scatters only the request's blocks, ``evict`` is an O(1) refcount
@@ -76,7 +77,7 @@ class PagedCache:
     __slots__ = ("blocks", "length")
 
     def __init__(self, blocks: Dict[str, Any], length: int):
-        # {"k","v": [L, nb, Bs, Hkvp*dh]}, or {"kv": [L, nb, Bs, lanes]}
+        # {"kv": [L, nb, Bs, lanes]}, rows laid out as the pool's
         self.blocks = blocks
         self.length = int(length)
 
@@ -295,8 +296,8 @@ class Engine:
     # ---- prefill role ---------------------------------------------------
 
     def _prefill_payload_impl(self, p, inputs):
-        """Full prefill -> (logits, handoff blocks). The cache is reshaped
-        to [L, nb, Bs, lanes] block tensors, one per pool array
+        """Full prefill -> (logits, handoff blocks). The cache is laid out
+        as the pool's rows and reshaped to [L, nb, Bs, lanes] blocks
         (block-padded true length — never the slot capacity); logits are
         computed before any padding, so they match the dense engine's
         bit-for-bit."""
@@ -304,15 +305,11 @@ class Engine:
         S = inputs["tokens"].shape[1]
         Bs = self.block_size
         Sb = -(-S // Bs) * Bs
-        blocks = {}
-        for kk in self.pool:
-            row = cache[kk][:, 0]              # [L, S, Hkvp, dh] or lanes
-            if Sb > S:
-                pad = jnp.zeros((row.shape[0], Sb - S) + row.shape[2:],
-                                row.dtype)
-                row = jnp.concatenate([row, pad], axis=1)
-            blocks[kk] = row.reshape(row.shape[0], Sb // Bs, Bs, -1)
-        return logits, blocks
+        rows = (cache["kv"] if self.cfg.mla
+                else T.kv_rows(cache["k"], cache["v"]))[:, 0]  # [L, S, lanes]
+        if Sb > S:
+            rows = jnp.pad(rows, ((0, 0), (0, Sb - S), (0, 0)))
+        return logits, {"kv": rows.reshape(rows.shape[0], Sb // Bs, Bs, -1)}
 
     def prefill(self, prompt: np.ndarray) -> Tuple[int, Any]:
         """Full prefill of one prompt; returns (first_token, payload)."""

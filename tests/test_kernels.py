@@ -79,17 +79,26 @@ def test_decode_attention_length_mask_exact():
 
 
 def _paged_case(rng, B, N, Bs, Hkv, dh, lengths, nb=None):
-    """Pool [N, Bs, Hkv*dh] + per-sequence tables of blocks scattered
-    through it; columns past a sequence's blocks -> trash block 0."""
-    pool_k = rng.standard_normal((N, Bs, Hkv * dh)).astype(np.float32)
-    pool_v = rng.standard_normal((N, Bs, Hkv * dh)).astype(np.float32)
+    """Pool [N, Bs, 2*Hkv*dh] (a row: K heads, then V heads) + per-sequence
+    tables of blocks scattered through it; columns past a sequence's
+    blocks (all of a length-0 one's) -> trash block 0."""
+    pool = rng.standard_normal((N, Bs, 2 * Hkv * dh)).astype(np.float32)
     nb = nb or max(-(-int(l) // Bs) for l in lengths)
     tables = np.zeros((B, nb), np.int32)
     ids = iter(rng.permutation(np.arange(1, N)).tolist())
     for b, l in enumerate(lengths):
         for j in range(-(-int(l) // Bs)):
             tables[b, j] = next(ids)
-    return pool_k, pool_v, tables
+    return pool, tables
+
+
+# the serving groupings at block size 8: qwen2.5-3b's Hkv 2 / G 8 and
+# qwen3-14b's Hkv 8 / G 5, each with a slot of length 0, one ending
+# mid-block and one ending mid-chunk (chunks of 256 and of 64 or 128 keys)
+PAGED_SERVING_CASES = [
+    (3, 16, 2, 128, 8, 64, (0, 13, 300)),
+    (3, 40, 8, 128, 8, 48, (0, 13, 200)),
+]
 
 
 @pytest.mark.parametrize("B,H,Hkv,dh,Bs,N,lengths", [
@@ -97,20 +106,44 @@ def _paged_case(rng, B, N, Bs, Hkv, dh, lengths, nb=None):
     (3, 4, 4, 128, 32, 16, (64, 1, 90)),
     (1, 16, 1, 64, 8, 64, (100,)),
     (2, 8, 8, 64, 64, 8, (64, 128)),
-])
+] + PAGED_SERVING_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_paged(B, H, Hkv, dh, Bs, N, lengths, dtype):
     rng = np.random.default_rng(11)
     q = rng.standard_normal((B, H, dh)).astype(np.float32)
-    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths)
+    pool, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths)
     lens = np.asarray(lengths, np.int32)
     out = decode_attention_paged(
-        jnp.asarray(q, dtype), jnp.asarray(pool_k, dtype),
-        jnp.asarray(pool_v, dtype), jnp.asarray(tables), jnp.asarray(lens),
-        interpret=True)
-    ref = decode_attention_paged_ref(q, pool_k, pool_v, tables, lens)
+        jnp.asarray(q, dtype), jnp.asarray(pool, dtype), jnp.asarray(tables),
+        jnp.asarray(lens), interpret=True)
+    ref = decode_attention_paged_ref(q, pool, tables, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32), ref,
                                atol=tol(dtype), rtol=tol(dtype))
+
+
+# Keys a chunk holds on the K-and-V row, at block size 8 and bf16: 256 at
+# qwen2.5-3b's widths (32 blocks of 8 KiB) and 128 at qwen3-14b's (16 of
+# 32 KiB), as when K and V had a pool each; the two buffers hold 0.5 and
+# 1 MiB of VMEM
+@pytest.mark.parametrize("arch,blocks", [("qwen2.5-3b", 32),
+                                         ("qwen3-14b", 16)])
+def test_chunk_keys_on_the_kv_row(arch, blocks):
+    from repro.configs import get_config
+    from repro.kernels.decode_attention.paged_decode import chunk_blocks
+    cfg = get_config(arch)
+    lanes = 2 * cfg.num_kv_heads * cfg.dh
+    assert chunk_blocks(8, lanes, 2, 544) == blocks
+    assert 2 * blocks * 8 * lanes * 2 == {32: 1 << 19, 16: 1 << 20}[blocks]
+
+
+def test_latent_chunk_keys_unchanged():
+    """The latent kernel's chunks keep 25 blocks (200 keys) at
+    DeepSeek-V3's 640-lane rows, block size 8, bf16."""
+    from repro.configs import get_config
+    from repro.kernels.decode_attention.paged_decode import (
+        LATENT_CHUNK_BYTES, chunk_blocks)
+    lanes = get_config("deepseek-v3").latent_lanes
+    assert chunk_blocks(8, lanes, 2, 320, max_bytes=LATENT_CHUNK_BYTES) == 25
 
 
 # The serving shapes (block size 8, dh 128) against the XLA decode core
@@ -123,26 +156,25 @@ def test_decode_attention_paged(B, H, Hkv, dh, Bs, N, lengths, dtype):
 def test_paged_kernel_matches_xla_decode(arch, dtype):
     from repro.configs import get_config
     from repro.kernels.decode_attention.paged_decode import chunk_blocks
-    from repro.models.transformer import _decode_attend
+    from repro.models.transformer import _decode_attend, split_kv_rows
     cfg = get_config(arch)
     H, Hkv, dh, Bs, nb = cfg.num_heads, cfg.num_kv_heads, cfg.dh, 8, 64
-    chunk = Bs * chunk_blocks(Bs, Hkv * dh, jnp.dtype(dtype).itemsize, nb)
+    chunk = Bs * chunk_blocks(Bs, 2 * Hkv * dh, jnp.dtype(dtype).itemsize,
+                              nb)
     # one key, one block, one past it, a chunk, one past a chunk, the
     # whole table; the last slot is inactive: pos 0 on the trash block
     lengths = (1, 8, 9, chunk, chunk + 1, nb * Bs)
     B = len(lengths) + 1
     rng = np.random.default_rng(5)
     N = 1 + sum(-(-l // Bs) for l in lengths)
-    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh,
-                                         lengths + (0,), nb)
+    pool, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths + (0,), nb)
     lens = np.asarray(lengths + (1,), np.int32)
     q = jnp.asarray(rng.standard_normal((B, H, dh)), dtype)
-    pk, pv = jnp.asarray(pool_k, dtype), jnp.asarray(pool_v, dtype)
-    out = decode_attention_paged(q, pk, pv, jnp.asarray(tables),
+    pj = jnp.asarray(pool, dtype)
+    out = decode_attention_paged(q, pj, jnp.asarray(tables),
                                  jnp.asarray(lens), interpret=True)
     W = nb * Bs
-    kd = pk[tables].reshape(B, W, Hkv, dh)
-    vd = pv[tables].reshape(B, W, Hkv, dh)
+    kd, vd = split_kv_rows(pj[tables].reshape(B, W, -1), cfg)
     valid = jnp.arange(W)[None, :] < lens[:, None]
     ref = _decode_attend(None, q[:, None], kd, vd, valid, cfg)[:, 0]
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -150,28 +182,37 @@ def test_paged_kernel_matches_xla_decode(arch, dtype):
                                atol=tol(dtype), rtol=tol(dtype))
 
 
-def test_decode_attention_paged_garbage_block_immunity():
-    """Trash-block contents and positions past `length` must not leak."""
+@pytest.mark.parametrize("B,H,Hkv,dh,Bs,N,lengths", [
+    (2, 4, 2, 64, 16, 16, (20, 33)),
+] + PAGED_SERVING_CASES)
+def test_decode_attention_paged_garbage_block_immunity(B, H, Hkv, dh, Bs, N,
+                                                       lengths):
+    """Trash-block contents and positions past `length` must not leak,
+    through either half of a row: K or V."""
     rng = np.random.default_rng(13)
-    B, H, Hkv, dh, Bs, N = 2, 4, 2, 64, 16, 16
-    lengths = np.array([20, 33], np.int32)
+    lengths = np.array(lengths, np.int32)
     q = rng.standard_normal((B, H, dh)).astype(np.float32)
-    pool_k, pool_v, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths,
-                                         nb=4)
+    # a table wider than the live blocks: its spare columns on the trash
+    pool, tables = _paged_case(rng, B, N, Bs, Hkv, dh, lengths,
+                               nb=-(-int(lengths.max()) // Bs) + 2)
     out1 = decode_attention_paged(
-        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
-        jnp.asarray(tables), jnp.asarray(lengths), interpret=True)
-    # poison the trash block AND the tail of each sequence's last block
-    pool_k2, pool_v2 = pool_k.copy(), pool_v.copy()
-    pool_k2[0] = 1e4
-    pool_v2[0] = -1e4
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(tables),
+        jnp.asarray(lengths), interpret=True)
+    # poison the trash block AND the tail of each sequence's last block,
+    # the K half and the V half of each row
+    half = Hkv * dh
+    pool2 = pool.copy()
+    pool2[0, :, :half] = 1e4
+    pool2[0, :, half:] = -1e4
     for b, l in enumerate(lengths):
+        if l == 0:
+            continue
         last = tables[b, (int(l) - 1) // Bs]
-        pool_k2[last, int(l) % Bs or Bs:] = 77.0
-        pool_v2[last, int(l) % Bs or Bs:] = -77.0
+        pool2[last, int(l) % Bs or Bs:, :half] = 77.0
+        pool2[last, int(l) % Bs or Bs:, half:] = -77.0
     out2 = decode_attention_paged(
-        jnp.asarray(q), jnp.asarray(pool_k2), jnp.asarray(pool_v2),
-        jnp.asarray(tables), jnp.asarray(lengths), interpret=True)
+        jnp.asarray(q), jnp.asarray(pool2), jnp.asarray(tables),
+        jnp.asarray(lengths), interpret=True)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
 
 
@@ -180,7 +221,7 @@ def _latent_case(rng, B, N, Bs, lanes, used, lengths, nb=None):
     the program pads its rows), with per-sequence tables as _paged_case."""
     pool = np.zeros((N, Bs, lanes), np.float32)
     pool[..., :used] = rng.standard_normal((N, Bs, used))
-    _, _, tables = _paged_case(rng, len(lengths), N, Bs, 1, 1, lengths, nb)
+    _, tables = _paged_case(rng, len(lengths), N, Bs, 1, 1, lengths, nb)
     return pool, tables
 
 
@@ -215,11 +256,13 @@ def test_decode_attention_mla_matches_its_twin(B, H, lanes, used, value, Bs,
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_latent_kernel_matches_xla_decode(dtype):
     from repro.configs import get_config
-    from repro.kernels.decode_attention.paged_decode import chunk_blocks
+    from repro.kernels.decode_attention.paged_decode import (
+        LATENT_CHUNK_BYTES, chunk_blocks)
     from repro.models.transformer import _mla_decode_attend, _mla_scale
     cfg = get_config("deepseek-v3")
     H, lanes, Bs, nb = cfg.num_heads, cfg.latent_lanes, 8, 64
-    chunk = Bs * chunk_blocks(Bs, lanes, jnp.dtype(dtype).itemsize, nb)
+    chunk = Bs * chunk_blocks(Bs, lanes, jnp.dtype(dtype).itemsize, nb,
+                              max_bytes=LATENT_CHUNK_BYTES)
     # one key, one block, one past it, a chunk, one past a chunk, the
     # whole table; the last slot is inactive: pos 0 on the trash block
     lengths = (1, 8, 9, chunk, chunk + 1, nb * Bs)

@@ -103,8 +103,10 @@ def test_insert_evict_returns_blocks(params):
     assert src._alloc.used == 0             # full prefill never touches pool
     free0 = dst._alloc.num_free
     slot = dst.insert(Request(rid=0, prompt=prompt, osl=4), cache)
-    nbk = cache.blocks["k"].shape[1]        # ceil(21/8) = 3 blocks/layer
+    assert set(cache.blocks) == {"kv"}      # a row: K heads, then V heads
+    nbk = cache.blocks["kv"].shape[1]       # ceil(21/8) = 3 blocks/layer
     assert nbk == 3
+    assert cache.blocks["kv"].shape[-1] == 2 * CFG.padded_kv_heads * CFG.dh
     assert dst._alloc.used == CFG.num_layers * nbk
     out = dst.decode_step({slot: tok})      # crosses 21 -> 24: same block
     dst.decode_step({slot: out[slot]})
@@ -208,6 +210,39 @@ def test_paged_payload_kv_bytes_is_block_rounded(params):
     _tok, dcache = dense.prefill(prompt)
     assert kv_bytes(dcache) == 256 * per_tok       # capacity-padded
     assert kv_bytes(cache) < kv_bytes(dcache)
+
+
+def test_paged_handoff_between_engines_decodes_as_the_source(params):
+    """A payload gathered off one paged engine's pool (chunked prefill,
+    ``gather_blocks``) and scattered into another's at other block ids
+    (``scatter_blocks``) decodes the tokens the source engine decodes
+    from it; it carries K and V in one array of the bytes two did."""
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(1, VOCAB, 21).astype(np.int32)
+    src = Engine(0, CFG, params, slots=2, capacity=64, chunk_size=8,
+                 paged=True)
+    dst = Engine(1, CFG, params, slots=2, capacity=64, paged=True)
+    tok, payload = src.prefill_chunked(prompt, 8)
+    assert set(payload.blocks) == {"kv"}
+    nbk = payload.num_blocks                # 24 padded tokens: 3 blocks
+    assert payload.nbytes == (2 * CFG.num_layers * nbk * 8
+                              * CFG.padded_kv_heads * CFG.dh * 4)
+    # another request first, so the payload lands on other block ids
+    _t, other = dst.prefill(rng.integers(1, VOCAB, 11).astype(np.int32))
+    dst.insert(Request(rid=0, prompt=prompt[:11], osl=4), other)
+
+    def decode(eng, rid):
+        slot = eng.insert(Request(rid=rid, prompt=prompt, osl=6), payload)
+        out, t = [], tok
+        for _ in range(6):
+            t = eng.decode_step({slot: t})[slot]
+            out.append(t)
+        return out, eng._tables[:, slot, :nbk].copy()
+
+    want, src_ids = decode(src, 1)
+    got, dst_ids = decode(dst, 2)
+    assert not np.array_equal(src_ids, dst_ids)
+    assert got == want
 
 
 def test_mixed_layout_handoff_rejected(params):

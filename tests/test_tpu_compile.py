@@ -90,15 +90,15 @@ KERNELS = {
         [((8, H, DH), BF), ((8, 2048, HKV, DH), BF),
          ((8, 2048, HKV, DH), BF), ((8,), I32)]),
     # the serving engine's default block_size (8) and one bf16 tile (16),
-    # over the pool's [N, Bs, Hkv*dh] layout
+    # over the pool's [N, Bs, 2*Hkv*dh] layout (a row: K heads, V heads)
     "paged_decode_bs8": (
         decode_attention_paged,
-        [((8, H, DH), BF), ((2049, 8, HKV * DH), BF),
-         ((2049, 8, HKV * DH), BF), ((8, 256), I32), ((8,), I32)]),
+        [((8, H, DH), BF), ((2049, 8, 2 * HKV * DH), BF), ((8, 256), I32),
+         ((8,), I32)]),
     "paged_decode_bs16": (
         decode_attention_paged,
-        [((8, H, DH), BF), ((1025, 16, HKV * DH), BF),
-         ((1025, 16, HKV * DH), BF), ((8, 128), I32), ((8,), I32)]),
+        [((8, H, DH), BF), ((1025, 16, 2 * HKV * DH), BF), ((8, 128), I32),
+         ((8,), I32)]),
     # DeepSeek-V3's latent rows: 128 heads, 640 lanes (576 used), 512
     # value lanes, block size 8
     "paged_latent_decode": (
@@ -140,8 +140,9 @@ def test_full_width_decode_step_paged_fits_v5e(one_chip, qwen_params):
     slots, capacity, bs = 8, 2048, 8
     nb = capacity // bs
     blocks = 1 + QWEN.num_layers * nb * (slots + 4)
-    kv = (blocks, bs, QWEN.padded_kv_heads * DH)
-    pool = dict(zip(("k", "v"), _shapes(one_chip, (kv, BF), (kv, BF))))
+    (kv,) = _shapes(one_chip, ((blocks, bs, 2 * QWEN.padded_kv_heads * DH),
+                               BF))
+    pool = {"kv": kv}
     tables, pos, tokens = _shapes(
         one_chip, ((QWEN.num_layers, slots, nb), I32), ((slots,), I32),
         ((slots,), I32))
@@ -164,16 +165,17 @@ KERNEL_STEPS = {
 @pytest.mark.parametrize("name", sorted(KERNEL_STEPS))
 def test_full_width_decode_step_on_kernel_fits_v5e(one_chip, name):
     """The paged decode step on the Pallas kernel reads the pool where it
-    lies: it fits HBM, and its temporaries stay under a quarter of one
-    pool, where any copy, reshape or transpose of the pool would need a
-    whole one (the kernel's first version relaid both pools out)."""
+    lies: it fits HBM, and its temporaries stay under a quarter of the
+    pool's K half, where any copy, reshape or transpose of the pool would
+    need a whole one (the kernel's first version relaid the pools out)."""
     cfg, slots, nb_max, nb = KERNEL_STEPS[name]
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         T.abstract_params(cfg))
     blocks = 1 + cfg.num_layers * nb_max * (slots + 4)
-    kv = (blocks, 8, cfg.padded_kv_heads * cfg.dh)
-    pool = dict(zip(("k", "v"), _shapes(one_chip, (kv, BF), (kv, BF))))
+    half = cfg.padded_kv_heads * cfg.dh
+    (kv,) = _shapes(one_chip, ((blocks, 8, 2 * half), BF))
+    pool = {"kv": kv}
     tables, pos, tokens = _shapes(
         one_chip, ((cfg.num_layers, slots, nb), I32), ((slots,), I32),
         ((slots,), I32))
@@ -182,8 +184,38 @@ def test_full_width_decode_step_on_kernel_fits_v5e(one_chip, name):
     compiled = step.lower(params, pool, tables, pos, tokens).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) <= TPU_V5E.hbm_cap
-    pool_bytes = blocks * 8 * kv[2] * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+    half_bytes = blocks * 8 * half * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < half_bytes / 4
+
+
+@pytest.mark.parametrize("step", ["prefill_chunked_paged", "scatter_blocks"])
+def test_pool_writes_stay_in_place_on_v5e(one_chip, qwen_params, step):
+    """The colocated engine's other programs on the pool (32 slots,
+    capacity 2560, block size 8): a chunked prefill of a 256-token prompt
+    in chunks of 128, and the insert of a 1024-token payload. Both write
+    the donated pool in place: their temporaries stay under a quarter of
+    the pool's K half, where a copy or relayout of the pool would need
+    the whole of it."""
+    L, half = QWEN.num_layers, QWEN.padded_kv_heads * DH
+    blocks = 1 + L * 320 * (32 + 4)
+    (kv,) = _shapes(one_chip, ((blocks, 8, 2 * half), BF))
+    pool = {"kv": kv}
+    if step == "prefill_chunked_paged":
+        tokens, tbl = _shapes(one_chip, ((1, 256), I32), ((L, 32), I32))
+        fn = jax.jit(lambda p, t, pool, tbl: T.prefill_chunked_paged(
+            p, QWEN, {"tokens": t}, 128, pool, tbl), donate_argnums=(2,))
+        lowered = fn.lower(qwen_params, tokens, pool, tbl)
+    else:
+        (ids,) = _shapes(one_chip, ((L, 128), I32))
+        payload = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(T.gather_blocks, pool, ids))
+        fn = jax.jit(T.scatter_blocks, donate_argnums=(0,))
+        lowered = fn.lower(pool, ids, payload)
+    compiled = lowered.compile()
+    half_bytes = blocks * 8 * half * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < half_bytes / 4
 
 
 def test_full_width_prefill_full_fits_v5e(one_chip, qwen_params):
